@@ -236,6 +236,24 @@ def client_trainables(state: FLState):
     return state.spec.unflatten_stacked(state.clients_tr)
 
 
+def _masked_mean(v, w):
+    """``sum(v * w) / max(sum(w), 1)`` over the client axis, summed in a
+    fixed pairwise order.
+
+    XLA may order a ``reduce`` differently depending on what it fuses it
+    with: the dense round fuses the loss sum with local SGD's mean over
+    the ``s`` steps, while the cohort round's scatter splits that fusion,
+    and the two sums then differ in the last bit.  Elementwise adds are
+    never reassociated, so summing by halving keeps every round path's
+    metric bit-identical for the same per-client values."""
+    x = v * w
+    while x.shape[0] > 1:
+        if x.shape[0] % 2:
+            x = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
+        x = x[0::2] + x[1::2]
+    return x[0] / jnp.maximum(jnp.sum(w), 1.0)
+
+
 def _clip(g, max_norm):
     if not max_norm:
         return g
@@ -554,8 +572,7 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
             safe = losses if fault_cfg is None else \
                 jnp.where(jnp.isfinite(losses), losses, 0.0)
             metrics = dict(
-                loss=jnp.sum(safe * mask)
-                / jnp.maximum(jnp.sum(mask), 1.0),
+                loss=_masked_mean(safe, mask),
                 n_active=jnp.sum(mask),
                 mean_echo=jnp.sum(
                     (state.t - state.tau).astype(jnp.float32) * mu0)
@@ -567,8 +584,7 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                 metrics.update(n_dropped=n_dropped, n_rejected=n_rejected)
         elif fault_cfg is None:
             metrics = dict(
-                loss=jnp.sum(losses * mask)
-                / jnp.maximum(jnp.sum(mask), 1.0),
+                loss=_masked_mean(losses, mask),
                 n_active=jnp.sum(mask),
                 mean_echo=jnp.sum(
                     (state.t - state.tau).astype(jnp.float32) * mask)
@@ -581,7 +597,7 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
             mu = mask_upload
             safe = jnp.where(jnp.isfinite(losses), losses, 0.0)
             metrics = dict(
-                loss=jnp.sum(safe * mu) / jnp.maximum(jnp.sum(mu), 1.0),
+                loss=_masked_mean(safe, mu),
                 n_active=jnp.sum(mask),
                 mean_echo=jnp.sum(
                     (state.t - state.tau).astype(jnp.float32) * mu)

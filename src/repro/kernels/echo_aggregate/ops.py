@@ -39,7 +39,7 @@ def echo_aggregate(x, y, mask, echo, eta_g, *, use_pallas=True, block_n=4096):
 
 
 def echo_aggregate_flat(clients_flat, x_end_flat, global_flat, mask, echo,
-                        eta_g, *, use_pallas=True, block_n=4096, upload=None):
+                        eta_g, *, use_pallas=True, upload=None):
     """Fused FedAWE update on the flat substrate: one launch, guard included.
 
     clients_flat, x_end_flat: [m, N] start / post-local-SGD stacks;
@@ -50,13 +50,13 @@ def echo_aggregate_flat(clients_flat, x_end_flat, global_flat, mask, echo,
     if use_pallas:
         return echo_aggregate_fused_pallas(
             clients_flat, x_end_flat, global_flat, mask, echo, eta_g,
-            block_n=block_n, interpret=_use_interpret(), upload=upload)
+            interpret=_use_interpret(), upload=upload)
     return echo_aggregate_fused_ref(clients_flat, x_end_flat, global_flat,
                                     mask, echo, eta_g, upload=upload)
 
 
 def echo_aggregate_tree(clients_tr, x_end, mask, echo, eta_g, global_tr, *,
-                        use_pallas=True, block_n=4096, upload=None):
+                        use_pallas=True, upload=None):
     """Tree version over client-stacked trainables — single fused launch.
 
     clients_tr: x_i start models [m, ...]; x_end: post-local-SGD models
@@ -68,5 +68,5 @@ def echo_aggregate_tree(clients_tr, x_end, mask, echo, eta_g, global_tr, *,
     out = echo_aggregate_flat(
         spec.flatten_stacked(clients_tr), spec.flatten_stacked(x_end),
         spec.flatten(global_tr), mask, echo, eta_g,
-        use_pallas=use_pallas, block_n=block_n, upload=upload)
+        use_pallas=use_pallas, upload=upload)
     return spec.unflatten(out)

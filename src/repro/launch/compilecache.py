@@ -4,23 +4,26 @@ Short grid runs are dominated by jit warm-up: every shape signature in a
 Section 7 sweep costs a fresh XLA compile even though the programs are
 byte-identical across invocations.  jax ships a persistent compilation
 cache (``jax.experimental.compilation_cache``) that serializes compiled
-executables to disk; this module points it at a KEYED directory —
-``~/.cache/repro-jax/<launch.mesh.backend_cache_tag()>`` by default, so
-caches never mix across jax versions or backends — and drops the
+executables to disk; ``enable`` points it at one directory and drops the
 min-compile-time floor to zero, because the grid's per-cell programs are
-exactly the small ones the default 1s floor would skip.  Re-runs (and CI,
-which restores the directory across jobs via ``actions/cache``) then skip
+exactly the small ones the default 1s floor would skip.  Re-runs then skip
 XLA entirely for every program already seen.
+
+Placement follows one rule.  When ``JAX_COMPILATION_CACHE_DIR`` is set,
+the cache lives in exactly that directory and nowhere else.  Otherwise it
+lives in ``.jax_cache/`` at the root of the checkout (gitignored), or in
+the directory a caller names explicitly.  jax's own cache key already
+holds the jax version, backend and device, so one directory serves every
+backend.
 
 ``counters()`` exposes the process-wide hit/miss counts via jax's
 monitoring events — surfaced as the ``derived`` column of the bench's
-``compile_time_s/*`` rows (``benchmarks/kernels_bench.py``) so the
-record shows whether a warm-up was served from disk.
+``compile_time_s/*`` rows (``benchmarks/kernels_bench.py``) and printed
+by ``chip_smoke.py``, so a record shows whether a warm-up was served from
+disk.
 
 CLI entry points: ``--compile-cache DIR|auto`` on
-``repro.launch.experiments`` and ``repro.launch.train``; the env var
-``JAX_COMPILATION_CACHE_DIR`` (read natively by jax) works too but skips
-the keyed-directory convention and the hit/miss listeners.
+``repro.launch.experiments`` and ``repro.launch.train``.
 """
 from __future__ import annotations
 
@@ -28,6 +31,10 @@ import os
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _REQ_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), *([os.pardir] * 3),
+    ".jax_cache")
 
 _COUNTS = {"hits": 0, "requests": 0}
 _LISTENING = False
@@ -35,14 +42,9 @@ _DIR: str | None = None
 
 
 def default_cache_dir() -> str:
-    """The keyed default: ``~/.cache/repro-jax/<backend_cache_tag()>``
-    (base overridable via ``REPRO_COMPILE_CACHE_BASE`` for CI runners
-    with odd home layouts)."""
-    from repro.launch.mesh import backend_cache_tag
-    base = os.environ.get(
-        "REPRO_COMPILE_CACHE_BASE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro-jax"))
-    return os.path.join(base, backend_cache_tag())
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache/`` at the
+    root of the checkout."""
+    return os.path.abspath(os.environ.get(ENV_VAR) or CHECKOUT_CACHE)
 
 
 def _on_event(event, **kwargs):
@@ -65,16 +67,19 @@ def _listen():
 
 
 def enable(cache_dir: str = "") -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if missing; ``''``/``'auto'`` resolve to ``default_cache_dir()``) and
-    register the hit/miss listeners.  Idempotent — repeated calls just
-    re-point the directory.  Returns the resolved absolute path."""
+    """Turn on jax's persistent compilation cache and register the
+    hit/miss listeners.  The directory is ``$JAX_COMPILATION_CACHE_DIR``
+    whenever that is set (an explicit ``cache_dir`` does not override
+    it); else ``cache_dir``, where ``''``/``'auto'`` mean the checkout's
+    ``.jax_cache/``.  Created if missing.  Idempotent — repeated calls
+    just re-point the directory.  Returns the resolved absolute path."""
     global _DIR
     import jax
 
-    path = cache_dir if cache_dir not in ("", "auto") else \
-        default_cache_dir()
-    path = os.path.abspath(os.path.expanduser(path))
+    if os.environ.get(ENV_VAR) or cache_dir in ("", "auto"):
+        path = default_cache_dir()
+    else:
+        path = os.path.abspath(os.path.expanduser(cache_dir))
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     # cache every program: the default 1s floor skips exactly the small

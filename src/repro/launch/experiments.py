@@ -1092,10 +1092,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "pre-padding behaviour)")
     ap.add_argument("--compile-cache", default="", metavar="DIR",
                     help="enable jax's persistent compilation cache in "
-                         "DIR ('auto' resolves to ~/.cache/repro-jax/"
-                         "<jax+backend tag>, see launch/compilecache); "
-                         "warm grid re-runs then skip XLA compilation "
-                         "entirely")
+                         "DIR ('auto' = .jax_cache/ at the checkout root; "
+                         "$JAX_COMPILATION_CACHE_DIR, when set, wins — see "
+                         "launch/compilecache); warm grid re-runs then "
+                         "skip XLA compilation entirely")
     ap.add_argument("--replicate", default="shared",
                     choices=["shared", "full"],
                     help="seed-replication mode: 'shared' starts every "

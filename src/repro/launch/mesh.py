@@ -9,11 +9,22 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 # v5e hardware constants used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12       # per chip
 HBM_BW = 819e9                 # bytes/s per chip
 ICI_BW = 50e9                  # bytes/s per link
+
+
+def _auto_mesh(shape, axes, devices):
+    """Mesh whose axes are all ``Auto``: the executors place arrays with
+    explicit ``NamedSharding``s and let the compiler propagate the rest.
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which ``vmap``
+    rejects inputs whose mapped axis carries different mesh axes (the seed
+    executor's sharded states beside its replicated data keys)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for {axes}={shape}, have {len(devs)} — "
             "run under launch/dryrun.py which forces 512 host devices")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return _auto_mesh(shape, axes, devs[:n])
 
 
 def make_test_mesh(*, multi_pod: bool = False):
@@ -37,7 +48,7 @@ def make_test_mesh(*, multi_pod: bool = False):
     devs = jax.devices()
     if len(devs) < n:
         raise RuntimeError(f"need {n} devices, have {len(devs)}")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return _auto_mesh(shape, axes, devs[:n])
 
 
 def seed_mesh_shape(n_seeds: int, n_devices: int, *, multi_pod: bool = False):
@@ -86,8 +97,8 @@ def make_seed_mesh(n_seeds: int, *, multi_pod: bool = False,
     if shape is None:
         return (make_test_mesh(multi_pod=multi_pod) if test
                 else make_production_mesh(multi_pod=multi_pod))
-    return jax.make_mesh(shape, ("seed", "pod", "data"),
-                         devices=devs[:math.prod(shape)])
+    return _auto_mesh(shape, ("seed", "pod", "data"),
+                      devs[:math.prod(shape)])
 
 
 def mesh_axis_sizes(mesh):
@@ -97,13 +108,3 @@ def mesh_axis_sizes(mesh):
 def n_chips(mesh):
     return int(mesh.devices.size)
 
-
-def backend_cache_tag() -> str:
-    """Key of the persistent compilation-cache directory (and of CI's
-    cache restore step): serialized XLA executables are only reusable
-    within one (jax version, backend, device kind), so the cache lives
-    under a tag naming exactly those — e.g. ``jax0.4.37-cpu-cpu`` or
-    ``jax0.4.37-tpu-TPU-v5e``.  See ``launch/compilecache``."""
-    import re
-    kind = re.sub(r"[^A-Za-z0-9_.-]+", "-", jax.devices()[0].device_kind)
-    return f"jax{jax.__version__}-{jax.default_backend()}-{kind}"

@@ -1,13 +1,11 @@
-"""FedAWE training launcher.
+"""FedAWE training launcher:
 
-Two tiers share this entry point:
-  * simulation tier (runs anywhere, incl. this CPU container):
-      python -m repro.launch.train --preset image --strategy fedawe \
-          --dynamics sine --rounds 300
-  * pod tier (TPU; the CPU container proves it via launch/dryrun.py):
-      python -m repro.launch.train --arch gemma2-2b --pod
-    which builds the same FedAWE round over the production mesh with the
-    sharding rules of sharding/rules.py.
+    python -m repro.launch.train --preset image --strategy fedawe \
+        --dynamics sine --rounds 300
+
+The same command runs on the CPU (JAX_PLATFORMS=cpu; the Pallas echo
+kernel in interpret mode) and on one TPU chip (the kernel compiled with
+Mosaic).  ``chip_smoke.py`` at the repo root drives it on the chip.
 """
 from __future__ import annotations
 
@@ -147,9 +145,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "--seeds > 1)")
     ap.add_argument("--compile-cache", default="", metavar="DIR",
                     help="enable jax's persistent compilation cache in "
-                         "DIR ('auto' resolves to ~/.cache/repro-jax/"
-                         "<jax+backend tag>, see launch/compilecache); "
-                         "warm re-runs skip XLA compilation entirely")
+                         "DIR ('auto' = .jax_cache/ at the checkout root; "
+                         "$JAX_COMPILATION_CACHE_DIR, when set, wins — see "
+                         "launch/compilecache); warm re-runs skip XLA "
+                         "compilation entirely")
     ap.add_argument("--sparse-cohort", type=int, default=0,
                     metavar="C_MAX",
                     help="O(cohort) rounds (core/cohort.py): gather the "

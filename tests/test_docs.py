@@ -150,7 +150,7 @@ def test_readme_shows_packed_mesh_and_compile_cache():
     README must keep the --packed --seed-mesh composition quickstart,
     the bucket-padding opt-out, the persistent-compilation-cache flag,
     and the new bench row families; BENCHMARKS.md must keep their
-    glossary rows and the cache-keying/CI-restore semantics."""
+    glossary rows and the cache-placement/CI-restore semantics."""
     text = open(README).read()
     for needle in ("--packed --seed-mesh", "--compile-cache",
                    "--no-pad-buckets", "--grid paper-sec7",
@@ -159,9 +159,28 @@ def test_readme_shows_packed_mesh_and_compile_cache():
     bench = open(os.path.join(REPO, "docs", "BENCHMARKS.md")).read()
     for needle in ("compile_count/<exec>", "dispatch_count/<exec>",
                    "compile_time_s/<exec>", "Persistent compilation cache",
-                   "backend_cache_tag", "actions/cache",
-                   "REPRO_COMPILE_CACHE_BASE", "--compile-cache"):
+                   "JAX_COMPILATION_CACHE_DIR", ".jax_cache",
+                   "actions/cache", "--compile-cache"):
         assert needle in bench, f"BENCHMARKS.md lost {needle}"
+
+
+def test_readme_shows_chip_runs():
+    """How the program runs on the chip stays documented: the README's
+    chip commands, the CPU rehearsal, and the compile tests it names;
+    every flag a chip command passes exists in chip_smoke.py."""
+    text = open(README).read()
+    for needle in ("python chip_smoke.py", "--four-chips",
+                   "JAX_PLATFORMS=cpu", "xla_force_host_platform_device_count",
+                   "tests/test_tpu_compile.py", "tpu_custom_call"):
+        assert needle in text, f"README lost {needle}"
+    chip_cmds = [c for c in _shell_commands() if "chip_smoke.py" in c]
+    assert chip_cmds, "README lost its chip_smoke.py command"
+    smoke = open(os.path.join(REPO, "chip_smoke.py")).read()
+    for cmd in chip_cmds:
+        for flag in re.findall(r"(--[A-Za-z][A-Za-z0-9-]*)", cmd):
+            assert f'"{flag}"' in smoke, f"chip_smoke.py lacks {flag}"
+    for doc in ("tests/test_tpu_compile.py", "chip_smoke.py"):
+        assert os.path.exists(os.path.join(REPO, doc)), doc
 
 
 def test_readme_shows_semi_async_quickstart():

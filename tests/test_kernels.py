@@ -6,9 +6,12 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
-from repro.kernels.echo_aggregate.kernel import echo_aggregate_pallas
+from repro.kernels.echo_aggregate.kernel import (BLOCK_M,
+                                                 echo_aggregate_fused_pallas,
+                                                 echo_aggregate_pallas)
 from repro.kernels.echo_aggregate.ops import echo_aggregate_tree
-from repro.kernels.echo_aggregate.ref import echo_aggregate_ref
+from repro.kernels.echo_aggregate.ref import (echo_aggregate_fused_ref,
+                                              echo_aggregate_ref)
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ops import flash_mha
 from repro.kernels.flash_attention.ref import mha_ref
@@ -51,6 +54,54 @@ def test_echo_aggregate_property(seed):
     ref = echo_aggregate_ref(x, y, mask, echo, eta)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4,
                                atol=1e-4)
+
+
+def _fused_inputs(m, N, dtype, seed):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(m, N)), dtype)
+    y = jnp.asarray(rng.normal(size=(m, N)), dtype)
+    g = jnp.asarray(rng.normal(size=N).astype(np.float32))
+    mask = jnp.asarray((rng.random(m) < 0.6).astype(np.float32))
+    echo = jnp.asarray(rng.integers(1, 9, m).astype(np.float32))
+    upload = jnp.asarray((rng.random(m) < 0.7).astype(np.float32))
+    return x, y, g, mask, echo, upload
+
+
+@pytest.mark.parametrize("m,N,dtype,upload", [
+    (8, 17, jnp.float32, False),
+    (BLOCK_M, 700, jnp.float32, True),
+    # several client blocks, the last one partial
+    (BLOCK_M + 44, 999, jnp.float32, True),
+    (2 * BLOCK_M + 88, 3000, jnp.bfloat16, False),
+])
+def test_echo_aggregate_fused_client_blocks(m, N, dtype, upload):
+    """The fused kernel reduces over client blocks of BLOCK_M rows; rows
+    past m in the last block must not leak into the mean."""
+    x, y, g, mask, echo, up = _fused_inputs(m, N, dtype, m)
+    up = up if upload else None
+    out = echo_aggregate_fused_pallas(x, y, g, mask, echo, 1.3, upload=up)
+    ref = echo_aggregate_fused_ref(x, y, g, mask, echo, 1.3, upload=up)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
+    empty = echo_aggregate_fused_pallas(x, y, g, jnp.zeros_like(mask), echo,
+                                        1.3, upload=up)
+    np.testing.assert_array_equal(np.asarray(empty), np.asarray(g))
+
+
+def test_echo_aggregate_fused_vmap_matches_loop():
+    """The seed executors vmap the kernel: each seed's slice equals the
+    unbatched call, bit for bit."""
+    seeds = [_fused_inputs(12, 300, jnp.float32, j) for j in range(3)]
+    batched = jax.vmap(
+        lambda x, y, g, mk, e, u: echo_aggregate_fused_pallas(
+            x, y, g, mk, e, 0.9, upload=u))(
+        *[jnp.stack(a) for a in zip(*seeds)])
+    for j, args in enumerate(seeds):
+        x, y, g, mk, e, u = args
+        np.testing.assert_array_equal(
+            np.asarray(batched[j]),
+            np.asarray(echo_aggregate_fused_pallas(x, y, g, mk, e, 0.9,
+                                                   upload=u)))
 
 
 def test_echo_aggregate_tree_matches_strategy_path():

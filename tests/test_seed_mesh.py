@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import (AvailabilityCfg, FLConfig, index_seed,
                         init_fl_state, make_grid_chunk_fn, make_round_fn,
@@ -108,6 +109,7 @@ def test_make_seed_mesh_degrades_to_standard_mesh():
     assert seed_axes_for(mesh) == "seed"
     # a seed-less mesh routes seeds over the client axes
     flat = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
                          devices=jax.devices()[:1])
     assert seed_axes_for(flat) == ("data",)
 
