@@ -1,0 +1,48 @@
+"""The check that decides ``correct``, driven through the rest of a run at a
+small size on the CPU: a sound run passes, the control (the reference one
+precision below the configuration, in the program's place) and every
+planted fault of the timed path fail."""
+import pytest
+
+from bench import harness, testing
+
+ONE_CHIP = ["cnn100_dense_sine", "cnn4k_cohort_sine", "cnn100_dense_allon"]
+
+
+@pytest.fixture
+def no_cache(monkeypatch):
+    from repro.launch import compilecache
+    monkeypatch.setattr(compilecache, "enable", lambda *a, **k: "")
+
+
+@pytest.mark.parametrize("name", ONE_CHIP)
+def test_sound_run_is_correct(name, no_cache):
+    res = testing.run_tiny(testing.tiny_cell(name))
+    assert res["correct"], res["check"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert list(res)[-1] == "check"
+    assert set(res["metrics"]) == {"rounds_per_s", "hbm_peak_gb", "setup_s"}
+
+
+@pytest.mark.parametrize("name", ONE_CHIP)
+def test_control_is_not_correct(name):
+    cell = testing.tiny_cell(name)
+    task = harness.traffic_mod.make_task(cell.cfg, cell.traffic,
+                                         testing.SEED)
+    rows = harness.sample_rows(cell, testing.SEED)
+    want = harness.follow(cell, testing.SEED, task, rows)
+    got = harness.follow(cell, testing.SEED, task, rows, dtype="bfloat16")
+    ok, shown = harness.verdict(harness.compare(cell, task, got, want),
+                                cell.limits)
+    assert not ok, shown
+
+
+@pytest.mark.parametrize("name,fault", [
+    (c, f) for c in ONE_CHIP
+    for f in ("state_unchanged", "half_batch", "echo_dropped")
+    # under full participation every echo is 1: dropping it changes nothing
+    if (c, f) != ("cnn100_dense_allon", "echo_dropped")])
+def test_fault_is_not_correct(name, fault, no_cache, monkeypatch):
+    testing.FAULTS[fault](monkeypatch)
+    res = testing.run_tiny(testing.tiny_cell(name))
+    assert not res["correct"], res["check"]
