@@ -57,6 +57,19 @@ Three executors drive the round function:
     bodies unrolled inside ONE donated jit — one dispatch advances a whole
     shape-compatible group of grid cells (C cells x S seeds x K rounds),
     the scaling step behind ``launch/experiments.py --packed``.
+
+Profiler names: each layer of a round runs under a ``jax.named_scope``
+that the compiled program keeps in its ``op_name`` metadata —
+``fl_sample`` (the device sampler), ``fl_availability`` (availability,
+faults, busy gating, cohort selection), ``fl_cohort_gather``,
+``fl_local_sgd``, ``fl_aggregate`` (innovations, upload masks, the
+strategy's aggregation, the staleness ring) and ``fl_cohort_scatter``.
+Every chunk executor marks chunk ``i`` with a ``StepTraceAnnotation``
+``fl_chunk`` (``step_num=i``) holding the host spans
+``fl_chunk_dispatch``, ``fl_chunk_fetch`` (the one metrics fetch),
+``fl_chunk_records`` and ``fl_chunk_hooks`` (eval, checkpoint, log).
+Neither costs device time; they are recorded only while a profiler
+trace runs.
 """
 from __future__ import annotations
 
@@ -266,23 +279,24 @@ def local_sgd(trainable, frozen, batches, rng, *, s, eta_l, loss_fn,
               grad_clip=0.0):
     """s local SGD steps. batches: pytree with leading step axis [s, ...].
     Returns (x_end, mean_loss)."""
-    gfn = jax.value_and_grad(loss_fn)
+    with jax.named_scope("fl_local_sgd"):
+        gfn = jax.value_and_grad(loss_fn)
 
-    def step(carry, inp):
-        x, key = carry
-        mb, _ = inp
-        key, sub = jax.random.split(key)
-        loss, g = gfn(x, frozen, mb, sub)
-        g = _clip(g, grad_clip)
-        x = jax.tree.map(
-            lambda xx, gg: (xx.astype(jnp.float32)
-                            - eta_l * gg.astype(jnp.float32)).astype(xx.dtype),
-            x, g)
-        return (x, key), loss
+        def step(carry, inp):
+            x, key = carry
+            mb, _ = inp
+            key, sub = jax.random.split(key)
+            loss, g = gfn(x, frozen, mb, sub)
+            g = _clip(g, grad_clip)
+            x = jax.tree.map(
+                lambda xx, gg: (xx.astype(jnp.float32) - eta_l
+                                * gg.astype(jnp.float32)).astype(xx.dtype),
+                x, g)
+            return (x, key), loss
 
-    (x_end, _), losses = jax.lax.scan(step, (trainable, rng),
-                                      (batches, jnp.arange(s)))
-    return x_end, jnp.mean(losses)
+        (x_end, _), losses = jax.lax.scan(step, (trainable, rng),
+                                          (batches, jnp.arange(s)))
+        return x_end, jnp.mean(losses)
 
 
 def make_round_fn(cfg: FLConfig, loss_fn: Callable, frozen: Any,
@@ -360,28 +374,34 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
         rng, k_av, k_loc = keys[0], keys[1], keys[2]
         k_up = keys[3] if fault_cfg is not None else None
         k_delay = keys[-1] if staleness_cfg is not None else None
-        mask, markov = sample_active(k_av, avail_cfg, base_p, state.t,
-                                     state.markov)
-        probs_t = probs_at(avail_cfg, base_p, state.t)
-        if fault_cfg is not None:
-            mask = _faults.compute_mask(fault_cfg, state.fault, mask,
-                                        state.t)
+        with jax.named_scope("fl_availability"):
+            mask, markov = sample_active(k_av, avail_cfg, base_p, state.t,
+                                         state.markov)
+            probs_t = probs_at(avail_cfg, base_p, state.t)
+            if fault_cfg is not None:
+                mask = _faults.compute_mask(fault_cfg, state.fault, mask,
+                                            state.t)
+            if staleness_cfg is not None:
+                # busy gating: an in-flight client (including one landing
+                # now) does not compute at t
+                mask = mask * (1.0 - _stale.busy_mask(state.stale))
+                delay = _stale.draw_delay(staleness_cfg, state.stale,
+                                          k_delay, state.t, cfg.m)
+            if c_max:
+                # cohort selection AFTER every availability layer (trace,
+                # blackout, busy gating): a slot is never wasted on a
+                # client that could not compute anyway.  Actives beyond
+                # the cap are deferred BEFORE local work — the effective
+                # mask zeroes them, so no computed update is ever
+                # silently dropped.
+                idx, n_deferred = _cohort.cohort_select(mask, c_max)
+                mask_c = jnp.take(mask, idx)
+                mask = jnp.zeros_like(mask).at[idx].set(mask_c)
         if staleness_cfg is not None:
-            # arrivals due this round, then busy gating: an in-flight
-            # client (including one landing now) does not compute at t
-            arrived, arr_age, arr_buf = _stale.drain(state.stale, state.t)
-            mask = mask * (1.0 - _stale.busy_mask(state.stale))
-            delay = _stale.draw_delay(staleness_cfg, state.stale, k_delay,
-                                      state.t, cfg.m)
-        if c_max:
-            # cohort selection AFTER every availability layer (trace,
-            # blackout, busy gating): a slot is never wasted on a client
-            # that could not compute anyway.  Actives beyond the cap are
-            # deferred BEFORE local work — the effective mask zeroes them,
-            # so no computed update is ever silently dropped.
-            idx, n_deferred = _cohort.cohort_select(mask, c_max)
-            mask_c = jnp.take(mask, idx)
-            mask = jnp.zeros_like(mask).at[idx].set(mask_c)
+            with jax.named_scope("fl_aggregate"):
+                # the ring's read side: arrivals due this round
+                arrived, arr_age, arr_buf = _stale.drain(state.stale,
+                                                         state.t)
 
         eta_l = cfg.eta_l
         if cfg.lr_schedule:
@@ -392,10 +412,14 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
             spec = state.spec
 
             def local(x0_flat, b, k):
-                xe, loss = local_sgd(spec.unflatten(x0_flat), frozen, b, k,
-                                     s=cfg.s, eta_l=eta_l, loss_fn=loss_fn,
-                                     grad_clip=cfg.grad_clip)
-                return spec.flatten(xe), loss
+                # a row's unflatten and flatten are local SGD's entry and
+                # exit on the flat substrate: they share its scope
+                with jax.named_scope("fl_local_sgd"):
+                    xe, loss = local_sgd(spec.unflatten(x0_flat), frozen, b,
+                                         k, s=cfg.s, eta_l=eta_l,
+                                         loss_fn=loss_fn,
+                                         grad_clip=cfg.grad_clip)
+                    return spec.flatten(xe), loss
 
             if c_max:
                 # cohort-local work at O(c): gather the cohort's data rows
@@ -404,49 +428,57 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                 # loc_rngs split over the full [m], so every cohort row
                 # consumes bitwise the batch columns and rng stream the
                 # dense engine would give that client.
-                cols, store = batches["cols"], batches["store"]
-                q = cols.shape[1]
-                b_c = _fed.gather_batches_at(
-                    store, jnp.take(cols, idx, axis=0), idx, cfg.s,
-                    q // cfg.s)
-                if strat.stateful_clients:
-                    start_c = _cohort.cohort_gather(state.clients_tr, idx)
-                else:
-                    start_c = jnp.broadcast_to(state.global_tr[None],
-                                               (c_max, spec.size))
-                x_end_c, losses_c = jax.vmap(local)(
-                    start_c, b_c, jnp.take(loc_rngs, idx, axis=0))
-                G_c = start_c - x_end_c
+                with jax.named_scope("fl_cohort_gather"):
+                    cols, store = batches["cols"], batches["store"]
+                    q = cols.shape[1]
+                    b_c = _fed.gather_batches_at(
+                        store, jnp.take(cols, idx, axis=0), idx, cfg.s,
+                        q // cfg.s)
+                    if strat.stateful_clients:
+                        start_c = _cohort.cohort_gather(state.clients_tr,
+                                                        idx)
+                    else:
+                        start_c = jnp.broadcast_to(state.global_tr[None],
+                                                   (c_max, spec.size))
+                    rngs_c = jnp.take(loc_rngs, idx, axis=0)
+                x_end_c, losses_c = jax.vmap(local)(start_c, b_c, rngs_c)
+                with jax.named_scope("fl_aggregate"):
+                    G_c = start_c - x_end_c
             if c_max and staleness_cfg is None:
                 # pure cohort round: aggregation, client/tau updates and
                 # the resident scatter all run at O(c·N)
-                tau_c = jnp.take(state.tau, idx)
-                mask_upload_c = None
-                if fault_cfg is not None:
-                    mask_upload_c, n_dropped, n_rejected = \
-                        _faults.upload_mask_cohort(fault_cfg, k_up, cfg.m,
-                                                   idx, mask_c, G_c)
-                    if fault_cfg.sanitize:
-                        keep = mask_upload_c[:, None] > 0
-                        x_end_c = jnp.where(keep, x_end_c, start_c)
-                        G_c = jnp.where(keep, G_c, 0.0)
-                mu_c = mask_c if mask_upload_c is None else mask_upload_c
-                mu_full = jnp.zeros((cfg.m,),
-                                    jnp.float32).at[idx].set(mu_c)
-                probs_c = jnp.take(probs_t, idx) \
-                    if getattr(probs_t, "ndim", 0) else probs_t
-                new_global, rows, write, new_extra = strat.aggregate_cohort(
-                    global_flat=state.global_tr, cohort_flat=start_c,
-                    x_end=x_end_c, G=G_c, mask=mask_c, t=state.t,
-                    tau_c=tau_c, probs_c=probs_c, extra=state.extra,
-                    eta_g=cfg.eta_g, m_total=cfg.m, idx=idx,
-                    mu_full=mu_full, use_kernel=cfg.use_kernel,
-                    mask_upload=mask_upload_c)
-                new_tau = jnp.where(mu_full > 0, state.t, state.tau)
+                with jax.named_scope("fl_aggregate"):
+                    tau_c = jnp.take(state.tau, idx)
+                    mask_upload_c = None
+                    if fault_cfg is not None:
+                        mask_upload_c, n_dropped, n_rejected = \
+                            _faults.upload_mask_cohort(fault_cfg, k_up,
+                                                       cfg.m, idx, mask_c,
+                                                       G_c)
+                        if fault_cfg.sanitize:
+                            keep = mask_upload_c[:, None] > 0
+                            x_end_c = jnp.where(keep, x_end_c, start_c)
+                            G_c = jnp.where(keep, G_c, 0.0)
+                    mu_c = mask_c if mask_upload_c is None else mask_upload_c
+                    mu_full = jnp.zeros((cfg.m,),
+                                        jnp.float32).at[idx].set(mu_c)
+                    probs_c = jnp.take(probs_t, idx) \
+                        if getattr(probs_t, "ndim", 0) else probs_t
+                    new_global, rows, write, new_extra = \
+                        strat.aggregate_cohort(
+                            global_flat=state.global_tr,
+                            cohort_flat=start_c, x_end=x_end_c, G=G_c,
+                            mask=mask_c, t=state.t, tau_c=tau_c,
+                            probs_c=probs_c, extra=state.extra,
+                            eta_g=cfg.eta_g, m_total=cfg.m, idx=idx,
+                            mu_full=mu_full, use_kernel=cfg.use_kernel,
+                            mask_upload=mask_upload_c)
+                    new_tau = jnp.where(mu_full > 0, state.t, state.tau)
                 new_clients = state.clients_tr
                 if rows is not None and new_clients is not None:
-                    new_clients = _cohort.cohort_scatter(
-                        state.clients_tr, idx, rows, write)
+                    with jax.named_scope("fl_cohort_scatter"):
+                        new_clients = _cohort.cohort_scatter(
+                            state.clients_tr, idx, rows, write)
                 # full-[m] metric inputs (O(m) vectors, not O(m·N)) so the
                 # shared metrics blocks below apply unchanged: scattered
                 # lanes carry exact zeros wherever the mask does
@@ -460,12 +492,13 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                     # scatter into dense lanes and the delivery / fault /
                     # aggregation code below runs unchanged — non-cohort
                     # lanes carry zero weight and G = 0 exactly
-                    if strat.stateful_clients:
-                        start = state.clients_tr.astype(jnp.float32)
-                    else:
-                        start = jnp.broadcast_to(state.global_tr[None],
-                                                 (cfg.m, spec.size))
-                    x_end = start.at[idx].set(x_end_c)
+                    with jax.named_scope("fl_cohort_scatter"):
+                        if strat.stateful_clients:
+                            start = state.clients_tr.astype(jnp.float32)
+                        else:
+                            start = jnp.broadcast_to(
+                                state.global_tr[None], (cfg.m, spec.size))
+                        x_end = start.at[idx].set(x_end_c)
                     losses = jnp.zeros((cfg.m,),
                                        jnp.float32).at[idx].set(losses_c)
                 else:
@@ -476,65 +509,66 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                                               (cfg.m, spec.size))
                     x_end, losses = jax.vmap(local)(start, batches,
                                                     loc_rngs)
-                G = start - x_end
-                if staleness_cfg is not None:
-                    # delivery candidates: synchronous computes (drawn
-                    # d = 0) plus ring-buffer arrivals — disjoint sets,
-                    # since an arriving client was busy and did not
-                    # compute this round
-                    now = mask * (delay == 0).astype(jnp.float32)
-                    defer = mask * (delay > 0).astype(jnp.float32)
-                    deliver = now + arrived
-                    G_eff = jnp.where(arrived[:, None] > 0, arr_buf,
-                                      jnp.where(now[:, None] > 0, G, 0.0))
-                    x_end_eff = jnp.where(arrived[:, None] > 0,
-                                          start - arr_buf, x_end)
-                    age_eff = jnp.where(arrived > 0, arr_age, 0.0)
-                else:
-                    deliver, G_eff, x_end_eff = mask, G, x_end
-                mask_upload = None
-                if fault_cfg is not None:
-                    # under staleness the fault layer acts at DELIVERY
-                    # time: a stale arrival can still drop mid-round or
-                    # fail sanitization when it lands
-                    mask_upload, n_dropped, n_rejected = \
-                        _faults.upload_mask(fault_cfg, k_up, deliver,
-                                            G_eff)
-                    if fault_cfg.sanitize:
-                        # scrub demoted rows: a 0-weighted NaN still
-                        # poisons a w·G reduction (0 * NaN = NaN), so
-                        # rejected clients' rows must hold finite values,
-                        # not just zero weight
-                        keep = mask_upload[:, None] > 0
-                        x_end_eff = jnp.where(keep, x_end_eff, start)
-                        G_eff = jnp.where(keep, G_eff, 0.0)
-                if staleness_cfg is not None:
-                    mu0 = deliver if mask_upload is None else mask_upload
-                    w_disc = mu0 if staleness_cfg.gamma >= 1.0 else \
-                        mu0 * jnp.power(jnp.float32(staleness_cfg.gamma),
-                                        age_eff)
-                    agg_mask, agg_kwargs = mu0, dict(mask_upload=w_disc,
-                                                     ages=age_eff)
-                else:
-                    agg_mask, agg_kwargs = mask, dict(
-                        mask_upload=mask_upload)
-                new_global, new_clients, new_tau, new_extra = \
-                    strat.aggregate_flat(
-                        global_flat=state.global_tr, clients_flat=start,
-                        x_end=x_end_eff, G=G_eff, mask=agg_mask,
-                        t=state.t, tau=state.tau, probs=probs_t,
-                        extra=state.extra, eta_g=cfg.eta_g,
-                        use_kernel=cfg.use_kernel, **agg_kwargs)
-                if staleness_cfg is not None:
-                    # raw (unsanitized, undiscounted) innovations enter
-                    # the ring; faults and the gamma discount apply at
-                    # delivery
-                    new_stale = _stale.step_buffer(state.stale, state.t,
-                                                   defer, delay, G)
-                if c_max and new_clients is not None:
-                    # demote the full stack back to residency (identity
-                    # for f32); the dense-lane aggregate ran in f32
-                    new_clients = new_clients.astype(rdt)
+                with jax.named_scope("fl_aggregate"):
+                    G = start - x_end
+                    if staleness_cfg is not None:
+                        # delivery candidates: synchronous computes (drawn
+                        # d = 0) plus ring-buffer arrivals — disjoint sets,
+                        # since an arriving client was busy and did not
+                        # compute this round
+                        now = mask * (delay == 0).astype(jnp.float32)
+                        defer = mask * (delay > 0).astype(jnp.float32)
+                        deliver = now + arrived
+                        G_eff = jnp.where(arrived[:, None] > 0, arr_buf,
+                                          jnp.where(now[:, None] > 0, G, 0.0))
+                        x_end_eff = jnp.where(arrived[:, None] > 0,
+                                              start - arr_buf, x_end)
+                        age_eff = jnp.where(arrived > 0, arr_age, 0.0)
+                    else:
+                        deliver, G_eff, x_end_eff = mask, G, x_end
+                    mask_upload = None
+                    if fault_cfg is not None:
+                        # under staleness the fault layer acts at DELIVERY
+                        # time: a stale arrival can still drop mid-round or
+                        # fail sanitization when it lands
+                        mask_upload, n_dropped, n_rejected = \
+                            _faults.upload_mask(fault_cfg, k_up, deliver,
+                                                G_eff)
+                        if fault_cfg.sanitize:
+                            # scrub demoted rows: a 0-weighted NaN still
+                            # poisons a w·G reduction (0 * NaN = NaN), so
+                            # rejected clients' rows must hold finite values,
+                            # not just zero weight
+                            keep = mask_upload[:, None] > 0
+                            x_end_eff = jnp.where(keep, x_end_eff, start)
+                            G_eff = jnp.where(keep, G_eff, 0.0)
+                    if staleness_cfg is not None:
+                        mu0 = deliver if mask_upload is None else mask_upload
+                        w_disc = mu0 if staleness_cfg.gamma >= 1.0 else \
+                            mu0 * jnp.power(jnp.float32(staleness_cfg.gamma),
+                                            age_eff)
+                        agg_mask, agg_kwargs = mu0, dict(mask_upload=w_disc,
+                                                         ages=age_eff)
+                    else:
+                        agg_mask, agg_kwargs = mask, dict(
+                            mask_upload=mask_upload)
+                    new_global, new_clients, new_tau, new_extra = \
+                        strat.aggregate_flat(
+                            global_flat=state.global_tr, clients_flat=start,
+                            x_end=x_end_eff, G=G_eff, mask=agg_mask,
+                            t=state.t, tau=state.tau, probs=probs_t,
+                            extra=state.extra, eta_g=cfg.eta_g,
+                            use_kernel=cfg.use_kernel, **agg_kwargs)
+                    if staleness_cfg is not None:
+                        # raw (unsanitized, undiscounted) innovations enter
+                        # the ring; faults and the gamma discount apply at
+                        # delivery
+                        new_stale = _stale.step_buffer(state.stale, state.t,
+                                                       defer, delay, G)
+                    if c_max and new_clients is not None:
+                        # demote the full stack back to residency (identity
+                        # for f32); the dense-lane aggregate ran in f32
+                        new_clients = new_clients.astype(rdt)
         else:
             start = state.clients_tr if strat.stateful_clients else \
                 tu.tree_broadcast(state.global_tr, cfg.m)
@@ -544,25 +578,28 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                                            eta_l=eta_l, loss_fn=loss_fn,
                                            grad_clip=cfg.grad_clip)
             )(start, batches, loc_rngs)
-            G = tu.tree_sub(start, x_end)
+            with jax.named_scope("fl_aggregate"):
+                G = tu.tree_sub(start, x_end)
 
-            mask_upload = None
-            if fault_cfg is not None:
-                mask_upload, n_dropped, n_rejected = _faults.upload_mask(
-                    fault_cfg, k_up, mask, G)
-                if fault_cfg.sanitize:
-                    keep = mask_upload > 0
-                    x_end = jax.tree.map(
-                        lambda xe, st_: jnp.where(
-                            tu._bshape(keep, xe), xe, st_), x_end, start)
-                    G = jax.tree.map(
-                        lambda g: jnp.where(tu._bshape(keep, g), g,
-                                            jnp.zeros_like(g)), G)
-            new_global, new_clients, new_tau, new_extra = strat.aggregate(
-                global_tr=state.global_tr, clients_tr=start, G=G, mask=mask,
-                t=state.t, tau=state.tau, probs=probs_t, extra=state.extra,
-                eta_g=cfg.eta_g, use_kernel=cfg.use_kernel, x_end=x_end,
-                mask_upload=mask_upload)
+                mask_upload = None
+                if fault_cfg is not None:
+                    mask_upload, n_dropped, n_rejected = _faults.upload_mask(
+                        fault_cfg, k_up, mask, G)
+                    if fault_cfg.sanitize:
+                        keep = mask_upload > 0
+                        x_end = jax.tree.map(
+                            lambda xe, st_: jnp.where(
+                                tu._bshape(keep, xe), xe, st_), x_end, start)
+                        G = jax.tree.map(
+                            lambda g: jnp.where(tu._bshape(keep, g), g,
+                                                jnp.zeros_like(g)), G)
+                new_global, new_clients, new_tau, new_extra = \
+                    strat.aggregate(
+                        global_tr=state.global_tr, clients_tr=start, G=G,
+                        mask=mask, t=state.t, tau=state.tau, probs=probs_t,
+                        extra=state.extra, eta_g=cfg.eta_g,
+                        use_kernel=cfg.use_kernel, x_end=x_end,
+                        mask_upload=mask_upload)
 
         if staleness_cfg is not None:
             # loss/n_active describe who COMPUTED this round; the delivery
@@ -656,8 +693,9 @@ def make_chunk_fn(cfg, round_fn, sample_fn, chunk_rounds, *,
     def _scan(state, frozen, sampler_state, store, data_key):
         def body(carry, _):
             st, ss = carry
-            batches, ss = sample_fn(store, ss,
-                                    jax.random.fold_in(data_key, st.t))
+            with jax.named_scope("fl_sample"):
+                batches, ss = sample_fn(store, ss,
+                                        jax.random.fold_in(data_key, st.t))
             if with_frozen:
                 st, metrics = round_fn(st, frozen, batches)
             else:
@@ -952,6 +990,22 @@ def _call_ckpt(ckpt_fn, state, done, sampler_state):
         ckpt_fn(state, done)
 
 
+def _dispatch(f, warmed, *args):
+    """One chunk dispatch of executor ``f``.  The first call per
+    executable stays unguarded: compilation commits baked constants to
+    device, an intentional one-time upload.  Every later dispatch is
+    transfer-free by construction (state, sampler carry, store and keys
+    are all device resident); the guard turns any regression — a numpy
+    batch or host scalar sneaking into the chunk call — into a hard error
+    instead of a silent per-chunk upload."""
+    if id(f) in warmed:
+        with jax.transfer_guard("disallow"):
+            return f(*args)
+    out = f(*args)
+    warmed.add(id(f))
+    return out
+
+
 def _run_rounds_chunked(state, round_fn, T, K, *, sample_fn, store, data_key,
                         sampler_state, chunk_fn, make_tail_fn, jit, donate,
                         log_every, eval_fn, eval_every, ckpt_fn, ckpt_every):
@@ -976,7 +1030,7 @@ def _run_rounds_chunked(state, round_fn, T, K, *, sample_fn, store, data_key,
         chunk_fn = make_chunk_fn(None, round_fn, sample_fn, K,
                                  donate=donate, jit=jit)
     tail_fn = None
-    history, done = [], 0
+    history, done, step = [], 0, 0
     warmed = set()
     while done < T:
         k = min(K, T - done)
@@ -988,34 +1042,27 @@ def _run_rounds_chunked(state, round_fn, T, K, *, sample_fn, store, data_key,
                            else make_chunk_fn(None, round_fn, sample_fn, k,
                                               donate=donate, jit=jit))
             f = tail_fn
-        if id(f) in warmed:
-            # steady-state dispatch is transfer-free by construction
-            # (state, sampler carry, store and key are all device
-            # resident); the guard turns any regression — a numpy batch
-            # or host scalar sneaking into the chunk call — into a hard
-            # error instead of a silent per-chunk upload.  The first
-            # call per executable stays unguarded: compilation commits
-            # baked constants to device, an intentional one-time upload.
-            with jax.transfer_guard("disallow"):
-                state, sampler_state, metrics = f(state, sampler_state,
-                                                  store, data_key)
-        else:
-            state, sampler_state, metrics = f(state, sampler_state, store,
-                                              data_key)
-            warmed.add(id(f))
-        metrics = jax.device_get(metrics)  # ONE host sync per chunk
-        for j in range(k):
-            rec = {key: float(v[j]) for key, v in metrics.items()}
-            rec["t"] = done + j
-            history.append(rec)
-        done += k
-        if eval_fn is not None and _crossed(done, k, eval_every):
-            history[-1].update(eval_fn(state))
-        if ckpt_fn is not None and _crossed(done, k, ckpt_every):
-            _call_ckpt(ckpt_fn, state, done, sampler_state)
-        if _crossed(done, k, log_every):
-            rec = history[-1]
-            print(f"[round {done:5d}] " +
-                  " ".join(f"{key}={v:.4f}" for key, v in rec.items()
-                           if key != "t"))
+        with jax.profiler.StepTraceAnnotation("fl_chunk", step_num=step):
+            with jax.profiler.TraceAnnotation("fl_chunk_dispatch"):
+                state, sampler_state, metrics = _dispatch(
+                    f, warmed, state, sampler_state, store, data_key)
+            with jax.profiler.TraceAnnotation("fl_chunk_fetch"):
+                metrics = jax.device_get(metrics)  # ONE host sync a chunk
+            with jax.profiler.TraceAnnotation("fl_chunk_records"):
+                for j in range(k):
+                    rec = {key: float(v[j]) for key, v in metrics.items()}
+                    rec["t"] = done + j
+                    history.append(rec)
+            done += k
+            with jax.profiler.TraceAnnotation("fl_chunk_hooks"):
+                if eval_fn is not None and _crossed(done, k, eval_every):
+                    history[-1].update(eval_fn(state))
+                if ckpt_fn is not None and _crossed(done, k, ckpt_every):
+                    _call_ckpt(ckpt_fn, state, done, sampler_state)
+                if _crossed(done, k, log_every):
+                    rec = history[-1]
+                    print(f"[round {done:5d}] " +
+                          " ".join(f"{key}={v:.4f}" for key, v in rec.items()
+                                   if key != "t"))
+        step += 1
     return state, history

@@ -153,5 +153,6 @@ def echo_aggregate_fused_pallas(x, y, g, mask, echo, eta_g, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="fedawe_echo_aggregate",
     )(stat, w[:, None], echo.astype(jnp.float32)[:, None], x, y,
       g.astype(jnp.float32)[None])[0, :N]

@@ -14,7 +14,12 @@ the cache lives in exactly that directory and nowhere else.  Otherwise it
 lives in ``.jax_cache/`` at the root of the checkout (gitignored), or in
 the directory a caller names explicitly.  jax's own cache key already
 holds the jax version, backend and device, so one directory serves every
-backend.
+backend.  ``enable`` also keys it on the program's op names: jax strips
+them by default, and a program that differs from a cached one only in
+its ``named_scope``s would be served the older executable, whose profile
+names the older scopes.  Locations then carry op names only, no source
+lines, so the key does not move with an edit elsewhere in a file or with
+the call site.
 
 ``counters()`` exposes the process-wide hit/miss counts via jax's
 monitoring events — surfaced as the ``derived`` column of the bench's
@@ -86,6 +91,9 @@ def enable(cache_dir: str = "") -> str:
     # per-cell programs the grid compiles most of
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # a served executable carries the scopes of the program that asked
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # jax probes the cache config ONCE, at the first compile, and latches
     # cache-off for the whole process if no directory was set yet —
     # reset_cache clears that latch (NOT any compiled executable), so

@@ -63,7 +63,7 @@ from repro.core import (FLConfig, index_seed, init_fl_state,
                         make_grid_chunk_fn, make_round_fn,
                         make_seeds_chunk_fn, stack_seeds)
 from repro.core.availability import KINDS, AvailabilityCfg
-from repro.core.engine import _crossed
+from repro.core.engine import _crossed, _dispatch
 from repro.core.strategies import REGISTRY
 from repro.data import (SAMPLING_MODES, init_seed_sampler_states,
                         make_device_sampler, seed_data_keys)
@@ -546,7 +546,7 @@ def run_seed_rounds(states, chunk_fn, T, K, *, sampler_states, store,
             "make_tail_fn(k) to build the S-batched tail executor, or "
             "make T a multiple of K")
     histories = [[] for _ in range(n_seeds)]
-    tail_fn, done = None, 0
+    tail_fn, done, step = None, 0, 0
     warmed = set()
     while done < T:
         k = min(K, T - done)
@@ -555,31 +555,29 @@ def run_seed_rounds(states, chunk_fn, T, K, *, sampler_states, store,
         else:
             tail_fn = tail_fn or make_tail_fn(k)
             f = tail_fn
-        if id(f) in warmed:
-            # warm S-batched dispatch is transfer-free (same rail as
-            # engine._run_rounds_chunked): seed-stacked carries, store
-            # and keys are device resident, so any implicit host upload
-            # here is a regression and fails loudly
-            with jax.transfer_guard("disallow"):
-                states, sampler_states, metrics = f(
-                    states, sampler_states, store, data_keys)
-        else:
-            states, sampler_states, metrics = f(states, sampler_states,
-                                                store, data_keys)
-            warmed.add(id(f))
-        metrics = jax.device_get(metrics)      # ONE host sync per dispatch
-        _append_seed_records(histories, metrics, k, done, n_seeds)
-        done += k
-        if eval_fn is not None and _crossed(done, k, eval_every):
-            for j in range(n_seeds):
-                histories[j][-1].update(eval_fn(index_seed(states, j)))
-        if ckpt_fn is not None and _crossed(done, k, ckpt_every):
-            ckpt_fn(states, done, sampler_states)
-        if _crossed(done, k, log_every):
-            mean_loss = sum(h[-1].get("loss", float("nan"))
-                            for h in histories) / n_seeds
-            print(f"[round {done:5d}] seeds={n_seeds} "
-                  f"mean_loss={mean_loss:.4f}")
+        # the host spans of engine._run_rounds_chunked, under its names
+        with jax.profiler.StepTraceAnnotation("fl_chunk", step_num=step):
+            with jax.profiler.TraceAnnotation("fl_chunk_dispatch"):
+                states, sampler_states, metrics = _dispatch(
+                    f, warmed, states, sampler_states, store, data_keys)
+            with jax.profiler.TraceAnnotation("fl_chunk_fetch"):
+                metrics = jax.device_get(metrics)  # ONE host sync a chunk
+            with jax.profiler.TraceAnnotation("fl_chunk_records"):
+                _append_seed_records(histories, metrics, k, done, n_seeds)
+            done += k
+            with jax.profiler.TraceAnnotation("fl_chunk_hooks"):
+                if eval_fn is not None and _crossed(done, k, eval_every):
+                    for j in range(n_seeds):
+                        histories[j][-1].update(
+                            eval_fn(index_seed(states, j)))
+                if ckpt_fn is not None and _crossed(done, k, ckpt_every):
+                    ckpt_fn(states, done, sampler_states)
+                if _crossed(done, k, log_every):
+                    mean_loss = sum(h[-1].get("loss", float("nan"))
+                                    for h in histories) / n_seeds
+                    print(f"[round {done:5d}] seeds={n_seeds} "
+                          f"mean_loss={mean_loss:.4f}")
+        step += 1
     return states, histories
 
 
@@ -956,7 +954,7 @@ def run_packed_group(cells, *, mesh=None, eval_every=0, log_every=0):
     packed = make_packed(K)
     tail_fn = None
     histories = [[[] for _ in range(seeds)] for _ in cells]
-    done = 0
+    done, step = 0, 0
     warmed = set()
     while done < T:
         k = min(K, T - done)
@@ -965,30 +963,30 @@ def run_packed_group(cells, *, mesh=None, eval_every=0, log_every=0):
         else:
             tail_fn = tail_fn or make_packed(k)
             f = tail_fn
-        if id(f) in warmed:
-            # warm packed dispatch is transfer-free (same rail as
-            # run_seed_rounds): every carry is device resident
-            with jax.transfer_guard("disallow"):
-                states_t, sampler_t, metrics_t = f(states_t, sampler_t,
-                                                   stores_t, keys_t)
-        else:
-            states_t, sampler_t, metrics_t = f(states_t, sampler_t,
-                                               stores_t, keys_t)
-            warmed.add(id(f))
-        metrics_t = jax.device_get(metrics_t)  # ONE host sync per dispatch
-        for ci, metrics in enumerate(metrics_t):
-            _append_seed_records(histories[ci], metrics, k, done, seeds)
-        done += k
-        if _crossed(done, k, eval_every):
-            for ci, c in enumerate(cells):
-                if c["eval_fn"] is None:
-                    continue
-                for j in range(seeds):
-                    histories[ci][j][-1].update(
-                        c["eval_fn"](index_seed(states_t[ci], j)))
-        if _crossed(done, k, log_every):
-            print(f"[round {done:5d}] packed group: {len(cells)} cells "
-                  f"x {seeds} seeds", flush=True)
+        # the host spans of engine._run_rounds_chunked, under its names
+        with jax.profiler.StepTraceAnnotation("fl_chunk", step_num=step):
+            with jax.profiler.TraceAnnotation("fl_chunk_dispatch"):
+                states_t, sampler_t, metrics_t = _dispatch(
+                    f, warmed, states_t, sampler_t, stores_t, keys_t)
+            with jax.profiler.TraceAnnotation("fl_chunk_fetch"):
+                metrics_t = jax.device_get(metrics_t)  # ONE host sync
+            with jax.profiler.TraceAnnotation("fl_chunk_records"):
+                for ci, metrics in enumerate(metrics_t):
+                    _append_seed_records(histories[ci], metrics, k, done,
+                                         seeds)
+            done += k
+            with jax.profiler.TraceAnnotation("fl_chunk_hooks"):
+                if _crossed(done, k, eval_every):
+                    for ci, c in enumerate(cells):
+                        if c["eval_fn"] is None:
+                            continue
+                        for j in range(seeds):
+                            histories[ci][j][-1].update(
+                                c["eval_fn"](index_seed(states_t[ci], j)))
+                if _crossed(done, k, log_every):
+                    print(f"[round {done:5d}] packed group: {len(cells)} "
+                          f"cells x {seeds} seeds", flush=True)
+        step += 1
     return states_t, histories
 
 

@@ -35,6 +35,8 @@ def _restored_cache_config():
     old_dir = jax.config.jax_compilation_cache_dir
     old_min_t = jax.config.jax_persistent_cache_min_compile_time_secs
     old_min_b = jax.config.jax_persistent_cache_min_entry_size_bytes
+    old_locs = jax.config.jax_traceback_in_locations_limit
+    old_meta = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         yield
     finally:
@@ -43,6 +45,9 @@ def _restored_cache_config():
                           old_min_t)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                           old_min_b)
+        jax.config.update("jax_traceback_in_locations_limit", old_locs)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          old_meta)
         cc.reset_cache()
 
 
@@ -100,3 +105,32 @@ def test_enable_persists_and_serves_from_disk(monkeypatch, tmp_path):
         after = compilecache.counters()
         assert after["hits"] >= mid["hits"] + 1, \
             "recompiling the same program must be served from disk"
+
+
+def test_cache_keys_on_scopes(monkeypatch, tmp_path):
+    """Two programs that differ only in a ``named_scope`` are two cache
+    entries: the second is compiled, not served the first's executable,
+    so its metadata names its own scope."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    x = jnp.arange(89, dtype=jnp.float32)
+
+    def scoped(name):
+        def program(x):
+            with jax.named_scope(name):
+                return (x * 2.375 - 0.125).sum()
+        return program
+
+    with _restored_cache_config():
+        compilecache.enable(str(tmp_path / "cc"))
+        first = jax.jit(scoped("fl_first")).lower(x).compile()
+        jax.clear_caches()
+        before = compilecache.counters()
+        second = jax.jit(scoped("fl_second")).lower(x).compile()
+        assert compilecache.counters()["misses"] == before["misses"] + 1
+        jax.clear_caches()
+        again = jax.jit(scoped("fl_second")).lower(x).compile()
+        assert compilecache.counters()["hits"] == before["hits"] + 1
+    assert "fl_first" in first.as_text()
+    assert "fl_second" in second.as_text() and \
+        "fl_first" not in second.as_text()
+    assert "fl_second" in again.as_text()
