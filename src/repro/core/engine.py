@@ -5,7 +5,13 @@ lockstep (vmap over the client axis; on the pod tier that axis is sharded
 over ('pod','data') and the aggregation lowers to collectives):
 
   1. local s-step SGD from each client's start model (per-client stale model
-     for FedAWE; the broadcast global for stateless baselines),
+     for FedAWE; the broadcast global for stateless baselines) — on the
+     flat substrate only for the round's available rows, compacted to the
+     front and trained ``LOCAL_SGD_BLOCK`` rows at a time in a
+     ``lax.fori_loop`` whose trip count is known only at run time (it
+     lowers to a while loop) and stops after the last available row; a
+     build whose client rows are split over devices (``client_shards``)
+     keeps one ``vmap`` over all ``m`` rows, so each device trains its own,
   2. innovation G_i = x_start − x_end,
   3. strategy aggregation (echo + implicit gossip for FedAWE).
 
@@ -79,6 +85,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import cohort as _cohort
 from repro.core import tree_util as tu
 from repro.core.availability import AvailabilityCfg, probs_at, sample_active
 from repro.core.flatten import FlatSpec, resident_dtype
@@ -89,6 +96,11 @@ from repro.core.strategies import Strategy, get_strategy
 class FLConfig:
     """Static config of the federated optimization (hashable; closed over
     by the jitted round function — changing any field retraces).
+
+    Dense flat rounds (``flat_state``, ``sparse_cohort == 0``) run local
+    SGD only for the round's available rows, in blocks of
+    ``LOCAL_SGD_BLOCK`` compacted rows; rows that do not compute keep
+    their start model (G = 0) and zero weight, as before.
 
     ``sparse_cohort`` > 0 switches the flat engine to the cohort-centric
     round path (core/cohort.py): the round's active client rows are
@@ -299,9 +311,74 @@ def local_sgd(trainable, frozen, batches, rng, *, s, eta_l, loss_fn,
         return x_end, jnp.mean(losses)
 
 
+# rows a block of dense local SGD: 8 clients x batch 32 = 256 images into
+# the paper CNN's first convolution, few rows past the last available one
+LOCAL_SGD_BLOCK = 8
+
+
+def _local_sgd_blocks(local, mask, start, batches, rngs):
+    """``jax.vmap(local)`` over only the rows whose ``mask`` is set.
+
+    The rows are put in ``cohort.cohort_select`` order (active first, lowest
+    index first), padded to whole blocks, and a ``lax.fori_loop`` of
+    ceil(n_active / block) trips (a while loop, the count being dynamic;
+    under a seed ``vmap`` every replicate runs the largest count and
+    discards the trips past its own) gathers ``LOCAL_SGD_BLOCK`` rows a trip,
+    trains them and writes them to a block-aligned slab.  One gather puts
+    the results back in client order: rows no block reached keep
+    ``x_end = start`` (G = 0) and loss 0; an inactive row that shares a
+    block with active ones is computed and carries zero weight downstream.
+    Every trained row consumes the batch and key of its own index, as in
+    the full ``vmap``.  Returns ``(x_end [m, N], losses [m], n_computed)``,
+    ``n_computed`` being the rows this replicate's trips ran, repeats
+    included.
+    """
+    m = mask.shape[0]
+    b = min(LOCAL_SGD_BLOCK, m)
+    n_pad = -(-m // b) * b
+    with jax.named_scope("fl_local_sgd"):
+        order, _ = _cohort.cohort_select(mask, m)
+        # whole blocks: the padding repeats rows, whose results are unread
+        order = jnp.concatenate([order, order[:n_pad - m]])
+        n_blocks = (jnp.sum(mask > 0, dtype=jnp.int32) + (b - 1)) // b
+        args = (start, batches, rngs)
+        # batch leaves [m, s, b, ...] are gathered as [m, s·b, features],
+        # which the compiler lays out with each client's samples in one
+        # contiguous run (on a v5e, rows of the layout it picks for the
+        # model's convolutions, client axis minor, took ~16 ms a block to
+        # gather; a flat [m, -1] view tripled the chunk's temporaries)
+        grouped = jax.tree.map(
+            lambda a: a.reshape(m, a.shape[1] * a.shape[2], -1)
+            if a.ndim > 2 else a, args)
+
+        def block(k, carry):
+            xs, ls = carry
+            rows = jax.lax.dynamic_slice_in_dim(order, k * b, b)
+            xe, loss = jax.vmap(local)(*jax.tree.map(
+                lambda a, g: jnp.take(g, rows, axis=0).reshape(
+                    (b,) + a.shape[1:]), args, grouped))
+            return (jax.lax.dynamic_update_slice_in_dim(xs, xe, k * b, 0),
+                    jax.lax.dynamic_update_slice_in_dim(ls, loss, k * b, 0))
+
+        xe_sds, loss_sds = jax.eval_shape(jax.vmap(local), *jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((b,) + a.shape[1:], a.dtype),
+            args))
+        init = (jnp.zeros((n_pad,) + xe_sds.shape[1:], xe_sds.dtype),
+                jnp.zeros((n_pad,), loss_sds.dtype))
+        xs, ls = jax.lax.fori_loop(0, n_blocks, block, init)
+        # client -> its place in order
+        pos = jnp.zeros((m,), jnp.int32).at[order[:m]].set(
+            jnp.arange(m, dtype=jnp.int32))
+        reached = pos < n_blocks * b
+        x_end = jnp.where(reached[:, None], jnp.take(xs, pos, axis=0), start)
+        losses = jnp.where(reached, jnp.take(ls, pos), 0.0)
+        n_computed = (n_blocks * b).astype(jnp.float32)
+    return x_end, losses, n_computed
+
+
 def make_round_fn(cfg: FLConfig, loss_fn: Callable, frozen: Any,
                   avail_cfg: AvailabilityCfg, base_p, fault_cfg=None,
-                  staleness_cfg=None):
+                  staleness_cfg=None, client_shards=1):
     """Build the jittable round function (frozen params closed over —
     fine when frozen is empty/small; the pod tier uses
     make_round_fn_with_frozen so FSDP-sharded bases stay runtime args).
@@ -311,7 +388,8 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, frozen: Any,
     """
     inner = make_round_fn_with_frozen(cfg, loss_fn, avail_cfg, base_p,
                                       fault_cfg=fault_cfg,
-                                      staleness_cfg=staleness_cfg)
+                                      staleness_cfg=staleness_cfg,
+                                      client_shards=client_shards)
 
     def round_fn(state: FLState, batches):
         return inner(state, frozen, batches)
@@ -321,7 +399,8 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, frozen: Any,
 
 def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                               avail_cfg: AvailabilityCfg, base_p,
-                              fault_cfg=None, staleness_cfg=None):
+                              fault_cfg=None, staleness_cfg=None,
+                              client_shards=1):
     """Variant taking frozen params as a runtime argument:
     (state, frozen, batches) -> (state, metrics).
 
@@ -347,7 +426,13 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
     mid-round or fail sanitization when it lands); the metrics dict grows
     ``n_stale`` / ``mean_staleness`` per round.  ``staleness_cfg=None``
     — or ``tau_max = 0``, normalized to None here — is byte-identical to
-    the synchronous engine."""
+    the synchronous engine.
+
+    ``client_shards`` is the number of devices the caller's shardings
+    split the ``[m, N]`` client rows over.  Above 1 the dense flat round
+    keeps one ``vmap`` over all rows, each device training its own: the
+    block loop would gather each block across the shards and train it on
+    every device."""
     strat = get_strategy(cfg.strategy)
     if fault_cfg is not None:
         from repro.core import faults as _faults
@@ -360,8 +445,8 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
             "staleness_cfg needs the flat [m, N] substrate (flat_state)"
         from repro.core import staleness as _stale
     c_max = min(int(cfg.sparse_cohort), cfg.m) if cfg.sparse_cohort else 0
+    full_vmap = client_shards > 1
     if c_max:
-        from repro.core import cohort as _cohort
         from repro.data import federated as _fed
         rdt = resident_dtype(cfg.resident_dtype)
         if staleness_cfg is None:
@@ -408,6 +493,9 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
             eta_l = cfg.eta_l / jnp.sqrt(state.t.astype(jnp.float32) / 10.0 + 1.0)
 
         loc_rngs = jax.random.split(k_loc, cfg.m)
+        # rows local SGD runs this round: the cohort, else every row unless
+        # the dense blocks below count their own
+        n_computed = jnp.float32(c_max or cfg.m)
         if cfg.flat_state:
             spec = state.spec
 
@@ -507,8 +595,12 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
                     start = state.clients_tr if strat.stateful_clients \
                         else jnp.broadcast_to(state.global_tr[None],
                                               (cfg.m, spec.size))
-                    x_end, losses = jax.vmap(local)(start, batches,
-                                                    loc_rngs)
+                    if full_vmap:
+                        x_end, losses = jax.vmap(local)(start, batches,
+                                                        loc_rngs)
+                    else:
+                        x_end, losses, n_computed = _local_sgd_blocks(
+                            local, mask, start, batches, loc_rngs)
                 with jax.named_scope("fl_aggregate"):
                     G = start - x_end
                     if staleness_cfg is not None:
@@ -644,6 +736,7 @@ def make_round_fn_with_frozen(cfg: FLConfig, loss_fn: Callable,
             )
         if c_max:
             metrics["n_deferred"] = n_deferred
+        metrics["n_computed"] = n_computed
         new_state = state._replace(
             global_tr=new_global, clients_tr=new_clients, tau=new_tau,
             t=state.t + 1, extra=new_extra, markov=markov, rng=rng)

@@ -39,8 +39,8 @@ from repro.models import (init_cache, init_params, lm_loss, merge_trainable,
                           split_trainable)
 from repro.models.model import prefill, serve_step
 from repro.sharding import (batch_pspecs, cache_pspecs, client_stack_pspecs,
-                            flat_pspecs, param_pspecs, sampler_pspecs,
-                            seed_pspecs, serve_batch_pspecs)
+                            flat_pspecs, mesh_client_shards, param_pspecs,
+                            sampler_pspecs, seed_pspecs, serve_batch_pspecs)
 
 I32 = jnp.int32
 F32 = jnp.float32
@@ -247,9 +247,12 @@ def build_chunk_train_step(cfg, shape, mesh, multi_pod, variant):
         n_flat = FlatSpec.from_tree(trainable_sds).size
         stale_sds = {"buf": _sds((staleness_cfg.tau_max, m, n_flat), F32),
                      "ages": _sds((staleness_cfg.tau_max, m), F32)}
-    round_fn = make_round_fn_with_frozen(fl, loss_fn, av, base_p,
-                                         fault_cfg=fault_cfg,
-                                         staleness_cfg=staleness_cfg)
+    S = _chunk_seeds(variant)
+    round_fn = make_round_fn_with_frozen(
+        fl, loss_fn, av, base_p, fault_cfg=fault_cfg,
+        staleness_cfg=staleness_cfg,
+        client_shards=mesh_client_shards(mesh, seeds=bool(S),
+                                         multi_pod=multi_pod))
     sampling = _chunk_sampling(variant)
     # the dry-run store gives every client exactly `cap` samples (below),
     # so the epoch permutation stack lowers at its production size
@@ -287,13 +290,13 @@ def build_chunk_train_step(cfg, shape, mesh, multi_pod, variant):
         idx=P(ca, None),
         counts=P(ca),
     )
-    metrics_spec = dict(loss=P(None), n_active=P(None), mean_echo=P(None))
+    metrics_spec = dict(loss=P(None), n_active=P(None), mean_echo=P(None),
+                        n_computed=P(None))
     if fault_cfg is not None:
         metrics_spec.update(n_dropped=P(None), n_rejected=P(None))
     if staleness_cfg is not None:
         metrics_spec.update(n_stale=P(None), mean_staleness=P(None))
 
-    S = _chunk_seeds(variant)
     if S:
         # S-batched multi-seed executor: FLState/SamplerState/data keys
         # grow a leading [S] axis.  On the plain mesh it takes over the

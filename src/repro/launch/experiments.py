@@ -68,6 +68,7 @@ from repro.core.strategies import REGISTRY
 from repro.data import (SAMPLING_MODES, init_seed_sampler_states,
                         make_device_sampler, seed_data_keys)
 from repro.launch import analysis
+from repro.sharding import mesh_client_shards
 
 
 # ---------------------------------------------------------------------------
@@ -674,7 +675,7 @@ def _pad_m_config(sc: Scenario, fl: FLConfig, base_p, pad_m: int, *,
 
 
 def _cell_task(sc: Scenario, *, m, s, batch, n_samples, preset, seed,
-               use_kernel, rounds=0, pad_m=0):
+               use_kernel, rounds=0, pad_m=0, client_shards=1):
     """Materialize one cell's task + round function: ``(fl, round_fn,
     ds, eval_fn, init_fn, fault_state, stale_state)``.
 
@@ -689,7 +690,9 @@ def _cell_task(sc: Scenario, *, m, s, batch, n_samples, preset, seed,
     ``stale_state`` is None for synchronous cells.  ``pad_m > m`` widens
     the client axis with zero-availability padding rows BEFORE the round
     function closes over ``base_p`` (see ``_pad_m_config``) — the data
-    partition keeps ``m`` real clients.
+    partition keeps ``m`` real clients.  ``client_shards`` is the number
+    of devices a seed mesh splits the client rows over
+    (``sharding.mesh_client_shards``), passed on to ``make_round_fn``.
     """
     # lazy import: train.py imports this module for --scenario/--seeds
     from repro.core import faults, staleness
@@ -736,7 +739,8 @@ def _cell_task(sc: Scenario, *, m, s, batch, n_samples, preset, seed,
                                    has_fault=fault_state is not None,
                                    has_stale=stale_state is not None)
     rf = make_round_fn(fl, loss_fn, {}, sc.availability(), base_p,
-                       fault_cfg=fc, staleness_cfg=stcfg)
+                       fault_cfg=fc, staleness_cfg=stcfg,
+                       client_shards=client_shards)
     return fl, rf, params, ds, eval_fn, init_fn, fault_state, stale_state
 
 
@@ -769,7 +773,8 @@ def run_scenario(sc: Scenario, *, seeds=4, rounds=24, chunk_rounds=8,
     fl, rf, params, ds, eval_fn, init_fn, fault_state, stale_state = \
         _cell_task(
             sc, m=m, s=s, batch=batch, n_samples=n_samples, preset=preset,
-            seed=seed, use_kernel=use_kernel, rounds=rounds)
+            seed=seed, use_kernel=use_kernel, rounds=rounds,
+            client_shards=mesh_client_shards(mesh, seeds=True))
     states, histories, finals = run_multi_seed(
         fl, rf, params, ds, sampling=sc.sampling, batch=batch, seeds=seeds,
         rounds=rounds, chunk_rounds=K, rng=jax.random.PRNGKey(seed),
@@ -787,7 +792,7 @@ def run_scenario(sc: Scenario, *, seeds=4, rounds=24, chunk_rounds=8,
 
 def build_cell(sc: Scenario, *, seeds, rounds, chunk_rounds, m, s, batch,
                n_samples, preset, seed, use_kernel=False,
-               replicate="shared", pad_m=0):
+               replicate="shared", pad_m=0, mesh=None):
     """Build everything one PACKED grid cell needs — task, round/sample
     fns, device store, and the stacked per-seed carry — without running
     it.  The returned dict is the unit ``pack_cells`` groups and
@@ -800,12 +805,14 @@ def build_cell(sc: Scenario, *, seeds, rounds, chunk_rounds, m, s, batch,
     (``data.federated.pad_store``) and padded Markov chains start (and
     stay) off.  ``cap_paddable`` in the returned dict marks cells whose
     sampler-cap column ``pack_cells(pad=True)`` may pad bit-exactly.
+    ``mesh`` is the seed mesh the cell will run on, if any.
     """
     K = _resolve_chunk_rounds(chunk_rounds, rounds)   # fail BEFORE task build
     fl, rf, params, ds, eval_fn, init_fn, fault_state, stale_state = \
         _cell_task(
             sc, m=m, s=s, batch=batch, n_samples=n_samples, preset=preset,
-            seed=seed, use_kernel=use_kernel, rounds=rounds, pad_m=pad_m)
+            seed=seed, use_kernel=use_kernel, rounds=rounds, pad_m=pad_m,
+            client_shards=mesh_client_shards(mesh, seeds=True))
     store = ds.device_store()
     if fl.m > m:
         from repro.data.federated import pad_store
@@ -1004,7 +1011,8 @@ def run_packed_grid(names, *, seeds=4, rounds=24, chunk_rounds=8, m=16,
     cells = [build_cell(get_scenario(n), seeds=seeds, rounds=rounds,
                         chunk_rounds=chunk_rounds, m=m, s=s, batch=batch,
                         n_samples=n_samples, preset=preset, seed=seed,
-                        use_kernel=use_kernel, replicate=replicate)
+                        use_kernel=use_kernel, replicate=replicate,
+                        mesh=mesh)
              for n in names]
     groups = pack_cells(cells, pad=pad)
     padded = sum(1 for c in cells if c.get("padded_cap"))

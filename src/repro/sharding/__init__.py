@@ -3,6 +3,7 @@ from repro.sharding.rules import (  # noqa: F401
     cache_pspecs,
     client_stack_pspecs,
     flat_pspecs,
+    mesh_client_shards,
     param_pspecs,
     sampler_pspecs,
     seed_axes_for,

@@ -18,6 +18,8 @@ correct (XLA only needs consistent specs, not maximal ones).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -252,6 +254,21 @@ def sampler_pspecs(mesh, sampler_sds, m, *, multi_pod=False):
         return P(*([None] * len(shape)))
 
     return jax.tree_util.tree_map_with_path(leaf, sampler_sds)
+
+
+def mesh_client_shards(mesh, *, seeds=False, multi_pod=None):
+    """Devices ``flat_pspecs`` splits one replicate's ``[m, N]`` client
+    rows over on ``mesh`` (1 without a mesh).  Under the S-batched seed
+    executor (``seeds``) the rows keep that placement only beneath a
+    dedicated ``'seed'`` axis; otherwise the seeds take the client axes
+    (``seed_pspecs``) and every replicate's rows sit on one device."""
+    if mesh is None:
+        return 1
+    ax = _axis_sizes(mesh)
+    if seeds and "seed" not in ax:
+        return 1
+    mp = ("pod" in ax) if multi_pod is None else multi_pod
+    return math.prod(ax[a] for a in _client_axes(ax, mp))
 
 
 def seed_axes_for(mesh, *, multi_pod=None):

@@ -208,8 +208,9 @@ def test_norm_cap_rejects_everything_freezes_global(flat):
 def test_metrics_keys_contract():
     _, h_plain = _run("fedawe", None, flat=True, chunk=False, T=1)
     _, h_fault = _run("fedawe", MIDROUND, flat=True, chunk=False, T=1)
-    assert set(h_plain[0]) == {"loss", "n_active", "mean_echo", "t"}
-    assert set(h_fault[0]) == {"loss", "n_active", "mean_echo",
+    assert set(h_plain[0]) == {"loss", "n_active", "mean_echo",
+                               "n_computed", "t"}
+    assert set(h_fault[0]) == {"loss", "n_active", "mean_echo", "n_computed",
                                "n_dropped", "n_rejected", "t"}
 
 

@@ -124,7 +124,10 @@ def _assert_parity(dense, sparse_out, *, exact=True, rtol=0.0, atol=0.0):
     for rd, rs in zip(hd, hs):
         assert set(rs) - set(rd) == {"n_deferred"}
         assert rs["n_deferred"] == 0.0
-        for k in rd:
+        # path accounting, like n_deferred: the dense blocks count
+        # ceil(n / block) * block rows, the cohort its cap
+        assert rs["n_computed"] == M
+        for k in set(rd) - {"n_computed"}:
             if exact:
                 np.testing.assert_array_equal(rd[k], rs[k], err_msg=k)
             else:

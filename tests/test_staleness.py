@@ -291,7 +291,8 @@ def test_tau_max_zero_bit_parity(chunk):
     s_off, h_off = _run("fedawe", StalenessCfg(tau_max=0), chunk=chunk)
     s_none, h_none = _run("fedawe", None, chunk=chunk)
     _assert_same(s_none, s_off, h_none, h_off, exact=True)
-    assert set(h_off[0]) == {"loss", "n_active", "mean_echo", "t"}
+    assert set(h_off[0]) == {"loss", "n_active", "mean_echo", "n_computed",
+                             "t"}
 
 
 def test_tau_max_zero_bit_parity_seeds():
@@ -343,11 +344,11 @@ def test_metrics_keys_contract():
     _, h_stale = _run("fedawe", DET1, chunk=False, T=1)
     fc = FaultCfg(upload_survival=0.7, sanitize=True)
     _, h_both = _run("fedawe", DET1, chunk=False, T=1, fault_cfg=fc)
-    assert set(h_stale[0]) == {"loss", "n_active", "mean_echo",
+    assert set(h_stale[0]) == {"loss", "n_active", "mean_echo", "n_computed",
                                "n_stale", "mean_staleness", "t"}
-    assert set(h_both[0]) == {"loss", "n_active", "mean_echo", "n_stale",
-                              "mean_staleness", "n_dropped", "n_rejected",
-                              "t"}
+    assert set(h_both[0]) == {"loss", "n_active", "mean_echo", "n_computed",
+                              "n_stale", "mean_staleness", "n_dropped",
+                              "n_rejected", "t"}
 
 
 # ---------------------------------------------------------------------------
