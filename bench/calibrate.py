@@ -85,7 +85,7 @@ def main(argv=None):
             ("half_batch", args.fault_seeds, dict(half_batch=True))):
         for seed in seeds:
             task = traffic.make_task(cell.cfg, cell.traffic, seed)
-            task.images.delete()
+            task.free_arrays()
             row_ids = harness.sample_rows(cell, seed)
             want = harness.follow(cell, seed, task, row_ids)
             got = harness.follow(cell, seed, task, row_ids, **variant)

@@ -28,7 +28,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from bench import reference, traffic as traffic_mod
+from bench import models, reference, traffic as traffic_mod
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -82,18 +82,22 @@ class Program:
     """The program's objects for one cell and seed: round function, device
     store, sampler, state and the compiled chunk program, built as the
     train CLI builds them (one chip) or as ``run_multi_seed`` does (seed
-    mesh).  Only the generated arrays of ``task`` come from the bench."""
+    mesh).  Only the generated arrays of ``task`` come from the bench.
+
+    The frozen base is a runtime argument of the round and the chunk
+    program (``make_round_fn_with_frozen``, ``make_chunk_fn(...,
+    with_frozen=True)``), placed on the device and never donated.  An
+    empty base has no leaves, so it adds no parameter to the program."""
 
     def __init__(self, cell: Cell, task: traffic_mod.Task):
+        import jax
         import jax.numpy as jnp
         from repro.core import engine
         from repro.core.availability import AvailabilityCfg
         from repro.data import federated
-        from repro.models import cnn
 
         cfg, tr = cell.cfg, cell.traffic
         dep, trn = cfg["deployment"], cfg["training"]
-        av = tr["availability"]
         self.K = int(tr["chunk_rounds"])
         self.seeds = int(dep["seeds"])
         self.mesh = dep["mesh"] == "seed"
@@ -103,38 +107,45 @@ class Program:
             use_kernel=dep["echo_kernel"], flat_state=True,
             grad_clip=trn["grad_clip"], sparse_cohort=dep["c_max"],
             resident_dtype=dep["resident_dtype"])
-        self.round_fn = engine.make_round_fn(
-            self.fl, cnn.make_image_loss_fn(cnn.cnn_apply), {},
-            AvailabilityCfg(kind=av["kind"], gamma=av.get("gamma", 0.3),
-                            period=av.get("period", 20)),
-            task.base_p)
-        # the program uploads its own copy of the images from the host;
-        # the bench's device copy goes first, so set-up never holds both
-        images = np.asarray(task.images)
-        task.images.delete()
-        task.images = None
-        self.store = federated.device_store(
-            dict(images=images, labels=task.labels), task.client_indices)
-        del images
+        loss_fn = models.of(cfg).loss_fn(cfg["model"])
+        avail = AvailabilityCfg(**{k: v for k, v in
+                                   tr["availability"].items()
+                                   if k != "base_p"})
+        self.round_fn = engine.make_round_fn_with_frozen(
+            self.fl, loss_fn, avail, task.base_p)
+        # the program uploads its own copy of the arrays from the host;
+        # the bench's device copies go first, so set-up never holds both
+        arrays = {k: np.asarray(v) for k, v in task.arrays.items()}
+        task.free_arrays()
+        self.store = federated.device_store(arrays, task.client_indices)
+        del arrays
         init_sampler, self.sample_fn = federated.make_device_sampler(
             self.fl.m, self.fl.s, trn["batch"], mode=tr["sampling"],
             min_count=trn["batch"],
             emit="cols" if self.fl.sparse_cohort else "batches")
         if self.mesh:
+            if jax.tree.leaves(task.frozen):
+                raise SystemExit("the seed mesh takes no frozen base: no "
+                                 "cell needs one there")
             self._build_seed_mesh(task, init_sampler)
-        else:
-            if self.seeds != 1:
-                raise SystemExit("a one-chip cell runs one seed")
-            # the executor donates the state, its key included: the
-            # program gets device copies of the task's host keys
-            self.state = engine.init_fl_state(jnp.asarray(task.state_key),
-                                              self.fl, task.params)
-            self.data_key = jnp.asarray(task.data_key)
-            self.sampler_state = init_sampler(self.store, self.data_key)
-            self.chunk = engine.make_chunk_fn(
-                self.fl, self.round_fn, self.sample_fn, self.K).lower(
-                self.state, self.sampler_state, self.store,
-                self.data_key).compile()
+            return
+        if self.seeds != 1:
+            raise SystemExit("a one-chip cell runs one seed")
+        # the executor donates the state, its key included: the
+        # program gets device copies of the task's host keys
+        self.state = engine.init_fl_state(jnp.asarray(task.state_key),
+                                          self.fl, task.params)
+        self.data_key = jnp.asarray(task.data_key)
+        self.sampler_state = init_sampler(self.store, self.data_key)
+        self.frozen = jax.device_put(task.frozen)
+        self.chunk = engine.make_chunk_fn(
+            self.fl, self.round_fn, self.sample_fn, self.K,
+            with_frozen=True).lower(
+            self.state, self.frozen, self.sampler_state, self.store,
+            self.data_key).compile()
+        frozen, chunk = self.frozen, self.chunk
+        self.chunk_fn = lambda state, sampler_state, store, data_key: chunk(
+            state, frozen, sampler_state, store, data_key)
 
     def _build_seed_mesh(self, task, init_sampler):
         import jax.numpy as jnp
@@ -145,8 +156,10 @@ class Program:
             self.fl, task.params, jnp.asarray(task.state_key),
             jnp.asarray(task.data_key),
             init_sampler, self.store, self.seeds)
+        # the seed executor's rounds take no frozen argument; the base is {}
         builder = experiments.build_seed_executor(
-            self.fl, self.round_fn, self.sample_fn, self.seeds,
+            self.fl, lambda state, batches: self.round_fn(state, {}, batches),
+            self.sample_fn, self.seeds,
             mesh=make_seed_mesh(self.seeds), states=states,
             sampler_states=ss, store=self.store, data_keys=keys)
         (self.state, self.sampler_state, self.store,
@@ -155,6 +168,7 @@ class Program:
         self.chunk = builder(self.K).lower(
             self.state, self.sampler_state, self.store,
             self.data_key).compile()
+        self.chunk_fn = self.chunk
 
     def memory(self) -> dict:
         ma = self.chunk.memory_analysis()
@@ -177,14 +191,14 @@ class Program:
         if self.mesh:
             from repro.launch import experiments
             self.state, hists = experiments.run_seed_rounds(
-                self.state, self.chunk, T, self.K,
+                self.state, self.chunk_fn, T, self.K,
                 sampler_states=self.sampler_state, store=self.store,
                 data_keys=self.data_key, n_seeds=self.seeds,
                 ckpt_fn=hook, ckpt_every=self.K)
             return hists
         self.state, hist = engine.run_rounds(
             self.state, self.round_fn, None, T, chunk_rounds=self.K,
-            chunk_fn=self.chunk, sample_fn=self.sample_fn, store=self.store,
+            chunk_fn=self.chunk_fn, sample_fn=self.sample_fn, store=self.store,
             data_key=self.data_key, sampler_state=self.sampler_state,
             ckpt_fn=hook, ckpt_every=self.K)
         return [hist]
@@ -224,10 +238,12 @@ class Program:
     def free(self):
         import jax
 
+        # the frozen base is the task's, which the reference is given
         for leaf in jax.tree.leaves((self.state, self.sampler_state,
                                      self.store)):
             leaf.delete()
-        self.state = self.sampler_state = self.store = self.chunk = None
+        self.state = self.sampler_state = self.store = self.frozen = None
+        self.chunk = self.chunk_fn = None
 
 
 # ---------------------------------------------------------------------------
@@ -341,21 +357,18 @@ def _seed_keys(cell: Cell, task, j):
 def follow(cell: Cell, seed: int, task, row_ids, **variant) -> list:
     """The reference's observations of every seed replicate's first chunk
     (``variant``: ``dtype``, ``half_batch``)."""
-    model, data = cell.cfg["model"], cell.traffic["data"]
-    images = traffic_mod.image_task(
-        traffic_mod.seed_key(seed, 1), task.labels,
-        n_classes=model["n_classes"], shape=tuple(model["input_shape"]),
-        margin=data["margin"], noise=data["noise"])
+    task.arrays = traffic_mod.sample_arrays(cell.cfg, cell.traffic, seed,
+                                            task.groups)
     out = []
     for j in range(int(cell.cfg["deployment"]["seeds"])):
         sk, dk = _seed_keys(cell, task, j)
         out.append(reference.follow(
-            cell.cfg, cell.traffic, images=images, labels=task.labels,
+            cell.cfg, cell.traffic, arrays=task.arrays,
             client_indices=task.client_indices, base_p=task.base_p,
-            params=task.params, state_key=sk, data_key=dk,
-            rounds=int(cell.traffic["chunk_rounds"]), row_ids=row_ids,
-            **variant))
-    images.delete()
+            params=task.params, frozen=task.frozen, state_key=sk,
+            data_key=dk, rounds=int(cell.traffic["chunk_rounds"]),
+            row_ids=row_ids, **variant))
+    task.free_arrays()
     return out
 
 
@@ -422,6 +435,7 @@ class RunInfo:
     hlo: str                    # the compiled chunk program's HLO text
     peaks: dict
     trace: Optional[object] = None   # bench.traces.Trace of the window
+    n_trainable: Optional[int] = None   # N, a client row's parameters
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
@@ -495,7 +509,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         info = RunInfo(cell=cell, rounds=T, seeds=prog.seeds,
                        chips=cell.chips, window_s=window_s,
                        histories=hists, chunk_stamps=stamps, memory=memory,
-                       hlo=hlo, peaks=peaks, trace=tr)
+                       hlo=hlo, peaks=peaks, trace=tr,
+                       n_trainable=len(reference.flatten(task.params)))
         result["metrics"] = _read_per_layer(cell, info)
         busy = tr.busy_s()
         dev.update(busy_s=busy, window_s=tr.window_s())

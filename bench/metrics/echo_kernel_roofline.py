@@ -6,7 +6,7 @@ from bench import flops, opnames
 
 
 def read(run):
-    dep, model = run.cell.cfg["deployment"], run.cell.cfg["model"]
+    dep = run.cell.cfg["deployment"]
     if not dep["echo_kernel"]:
         return None
     seconds, calls = run.trace.op_seconds(
@@ -17,6 +17,6 @@ def read(run):
         raise opnames.MissingOp(f"{calls} echo kernel executions for "
                                 f"{expected} rounds")
     rows = dep["c_max"] or dep["m"]
-    nbytes = flops.echo_kernel_bytes(rows, flops.cnn_param_count(model))
+    nbytes = flops.echo_kernel_bytes(rows, run.n_trainable)
     least = calls * nbytes / run.peaks["hbm_bytes_per_s"]
     return 100.0 * least / seconds

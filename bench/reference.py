@@ -1,14 +1,16 @@
 """Plain FedAWE reference, and the comparison that decides ``correct``.
 
 ``follow`` re-derives a cell's first rounds from the same seed-made inputs
-(weights, images, shards, base probabilities, PRNG keys) without any of the
-program's code: availability draws, cohort selection with deferral, the
-uniform per-client sampler, local SGD through the CNN in plain ``jax.numpy``
-(f32 at ``Precision.HIGHEST``), FedAWE's adaptive echo and gossip mean, the
-tau and client-state updates, and the resident dtype of the client stack.
+(weights, the frozen base, per-sample arrays, shards, base probabilities,
+PRNG keys) without any of the program's code: availability draws of every
+kind (``bench/availability/<kind>.py``), cohort selection with deferral,
+the uniform per-client sampler, local SGD through the model module's
+reference loss (``bench/models/<kind>.py``, plain ``jax.numpy``, f32 at
+``Precision.HIGHEST``), FedAWE's adaptive echo and gossip mean, the tau
+and client-state updates, and the resident dtype of the client stack.
 The random streams are the program's documented ones: per round the state
-key splits into (next, availability, local) keys, availability is
-``uniform < p_i f(t)``, and the sampler draws
+key splits into (next, availability, local) keys, availability draws one
+uniform a client from its key, and the sampler draws
 ``randint(fold_in(data_key, t), (m, s*b), 0, counts)`` columns into each
 client's shard.
 
@@ -21,12 +23,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import json
 import math
 from typing import List
 
 import numpy as np
 
-CLIENT_BLOCK = 32   # clients per local-SGD call: one compiled shape
+from bench import availability, models
 
 
 @dataclasses.dataclass
@@ -64,65 +67,32 @@ def split_leaves(flat, offsets):
             for i in range(len(offsets) - 1)]
 
 
-def cnn_forward(params, x, precision):
-    """C(3,c0)-R-M-...-L(hidden)-R-L(classes): 3x3 'SAME' convolutions with
-    bias and ReLU, each followed by a 2x2 max-pool, then ReLU dense layers
-    and a linear head.  x: [B, H, W, C] -> logits [B, classes]."""
-    import jax
-    import jax.numpy as jnp
-
-    h = x
-    j = 0
-    while f"conv{j}" in params:
-        p = params[f"conv{j}"]
-        h = jax.lax.conv_general_dilated(
-            h, p["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=precision) + p["b"]
-        h = jax.nn.relu(h)
-        h = jax.lax.reduce_window(h, -jnp.inf,
-                                  jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1),
-                                  "VALID")
-        j += 1
-    h = h.reshape(h.shape[0], -1)
-    j = 0
-    while f"fc{j}" in params:
-        p = params[f"fc{j}"]
-        h = jax.nn.relu(jnp.dot(h, p["w"], precision=precision) + p["b"])
-        j += 1
-    p = params["head"]
-    return jnp.dot(h, p["w"], precision=precision) + p["b"]
-
-
-def xent(logits, labels):
-    import jax
-    import jax.numpy as jnp
-
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - picked)
-
-
 @functools.lru_cache(maxsize=None)
-def _make_block_fn(*, s, b, grad_clip, eta_g, dtype, half_batch,
+def _make_block_fn(*, model_json, s, b, grad_clip, eta_g, dtype, half_batch,
                    resident, precision):
     """Local SGD for one block of clients and their share of the FedAWE
-    sum, in one jitted call.
+    sum, in one jitted call, through the model module's reference loss.
 
     starts come from ``history[start_slot]`` (the global each client last
     received, rounded to the resident dtype), so the call's shapes never
-    change with the round."""
+    change with the round.  The frozen base enters as it is, in ``dtype``
+    where it is floating point."""
     import jax
     import jax.numpy as jnp
 
+    model = json.loads(model_json)
+    loss_of = models.load(model["kind"]).reference_loss
     precision = jax.lax.Precision[precision.upper()]
     bb = b // 2 if half_batch else b
 
-    def sgd(params, xs, ys, eta):
-        def step(p, xy):
-            x, y = xy
+    def low(a):
+        return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) \
+            else a
+
+    def sgd(params, frozen, batch, eta):
+        def step(p, mb):
             loss, g = jax.value_and_grad(
-                lambda q: xent(cnn_forward(q, x, precision), y))(p)
+                lambda q: loss_of(model, q, frozen, mb, precision))(p)
             sq = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                      for l in jax.tree.leaves(g))
             scale = jnp.minimum(1.0, grad_clip
@@ -132,22 +102,21 @@ def _make_block_fn(*, s, b, grad_clip, eta_g, dtype, half_batch,
                 .astype(dtype), p, g)
             return p, loss.astype(jnp.float32)
 
-        p, losses = jax.lax.scan(step, params, (xs, ys))
+        p, losses = jax.lax.scan(step, params, batch)
         return p, jnp.mean(losses)
 
     @jax.jit
-    def block(history, start_slot, weight, echo, images, labels, ids, eta):
+    def block(history, frozen, start_slot, weight, echo, arrays, ids, eta):
         # history: tree of [K+1, ...] f32 globals; start_slot, weight,
         # echo: [B]; ids: [B, s*b] sample indices
         starts = jax.tree.map(
             lambda h: h[start_slot].astype(resident).astype(jnp.float32),
             history)
-        xs = images[ids].reshape((ids.shape[0], s, b) + images.shape[1:])
-        ys = labels[ids].reshape((ids.shape[0], s, b))
-        xs, ys = xs[:, :, :bb].astype(dtype), ys[:, :, :bb]
+        batch = {k: low(v[ids].reshape((ids.shape[0], s, b) + v.shape[1:])
+                        [:, :, :bb]) for k, v in arrays.items()}
         p0 = jax.tree.map(lambda a: a.astype(dtype), starts)
-        ends, losses = jax.vmap(sgd, in_axes=(0, 0, 0, None))(
-            p0, xs, ys, eta.astype(dtype))
+        ends, losses = jax.vmap(sgd, in_axes=(0, None, 0, None))(
+            p0, jax.tree.map(low, frozen), batch, eta.astype(dtype))
         # FedAWE echo: x_i - eta_g (t - tau_i) (x_i - y_i), summed with
         # weight 1 per trained client (0 for the block's padding)
         part = jax.tree.map(
@@ -163,42 +132,26 @@ def _make_block_fn(*, s, b, grad_clip, eta_g, dtype, half_batch,
     return block
 
 
-def availability_probs(avail: dict, base_p, t):
-    """p_i f(t), clipped to [0, 1]: ``stationary`` has f = 1, ``sine`` has
-    f(t) = gamma sin(2 pi t / period) + 1 - gamma (the paper's Appendix
-    J.3)."""
-    import jax.numpy as jnp
-
-    kind = avail["kind"]
-    tt = jnp.asarray(t, jnp.float32)
-    if kind == "stationary":
-        f = jnp.ones_like(tt)
-    elif kind == "sine":
-        gamma, period = avail["gamma"], avail["period"]
-        f = gamma * jnp.sin(2 * jnp.pi * tt / period) + (1 - gamma)
-    else:
-        raise ValueError(f"the reference has no availability kind {kind!r}")
-    return jnp.clip(base_p * f, 0.0, 1.0)
-
-
-def follow(cfg: dict, traffic: dict, *, images, labels, client_indices,
-           base_p, params, state_key, data_key, rounds: int, row_ids,
+def follow(cfg: dict, traffic: dict, *, arrays, client_indices, base_p,
+           params, frozen, state_key, data_key, rounds: int, row_ids,
            dtype="float32", half_batch=False, precision=None) -> Observed:
     """Run ``rounds`` FedAWE rounds of one seed replicate from its initial
-    inputs and observe them as ``Observed``.  Matrix products run at
-    ``Precision.HIGHEST`` in f32 and at the default precision in a lower
-    ``dtype``; ``precision`` overrides that choice."""
+    inputs and observe them as ``Observed``.  ``arrays`` are the
+    per-sample arrays, ``params`` the trainable weights and ``frozen`` the
+    base the model module's reference loss is given as it is.  Matrix
+    products run at ``Precision.HIGHEST`` in f32 and at the default
+    precision in a lower ``dtype``; ``precision`` overrides that choice."""
     import jax
     import jax.numpy as jnp
 
     dep, tr = cfg["deployment"], cfg["training"]
-    avail = traffic["availability"]
     m, s, b = dep["m"], tr["s"], tr["batch"]
     c_max = dep["c_max"]
     if dep["strategy"] != "fedawe":
         raise ValueError("the reference implements FedAWE only")
     if traffic["sampling"] != "uniform":
         raise ValueError("the reference implements the uniform sampler only")
+    client_block = models.of(cfg).CLIENT_BLOCK
     dt = jnp.dtype(dtype)
     resident = jnp.dtype(dep["resident_dtype"])
     q = s * b
@@ -210,12 +163,11 @@ def follow(cfg: dict, traffic: dict, *, images, labels, client_indices,
     counts_dev = jnp.asarray(counts)
     if precision is None:
         precision = "highest" if dt == jnp.float32 else "default"
-    block = _make_block_fn(s=s, b=b, grad_clip=tr["grad_clip"],
-                           eta_g=tr["eta_g"], dtype=dt,
-                           half_batch=half_batch, resident=resident,
-                           precision=precision)
-    draw = jax.jit(lambda k, p: jax.random.uniform(k, p.shape) < p)
-    probs = jax.jit(lambda p, t: availability_probs(avail, p, t))
+    block = _make_block_fn(
+        model_json=json.dumps(cfg["model"], sort_keys=True), s=s, b=b,
+        grad_clip=tr["grad_clip"], eta_g=tr["eta_g"], dtype=dt,
+        half_batch=half_batch, resident=resident, precision=precision)
+    avail = availability.make(traffic["availability"], base_p)
     cols_of = jax.jit(lambda k: jax.random.randint(
         k, (m, q), 0, counts_dev[:, None]))
 
@@ -225,14 +177,13 @@ def follow(cfg: dict, traffic: dict, *, images, labels, client_indices,
         .at[0].set(a), g0)
     last = np.full((m,), -1, np.int64)     # round of last delivery
     tau = np.full((m,), -1, np.int64)
-    images = jnp.asarray(images)
-    labels = jnp.asarray(labels)
+    arrays = {k: jnp.asarray(v) for k, v in arrays.items()}
     losses, n_act, n_def = [], [], []
     key = jnp.asarray(state_key)
     for t in range(rounds):
         keys = jax.random.split(key, 3)
         key, k_av = keys[0], keys[1]
-        mask = np.asarray(draw(k_av, probs(base_p, t)))
+        mask = avail.draw(k_av, t)
         active = np.nonzero(mask)[0]
         # the lowest-index c_max available clients train; the rest defer
         trained = active[:c_max] if c_max else active
@@ -244,17 +195,17 @@ def follow(cfg: dict, traffic: dict, *, images, labels, client_indices,
             else jnp.float32(tr["eta_l"])
         total = jax.tree.map(jnp.zeros_like, g0)
         client_losses = []
-        for lo in range(0, len(trained), CLIENT_BLOCK):
-            ids_c = trained[lo:lo + CLIENT_BLOCK]
+        for lo in range(0, len(trained), client_block):
+            ids_c = trained[lo:lo + client_block]
             n = len(ids_c)
-            pad = CLIENT_BLOCK - n
+            pad = client_block - n
             sel = np.concatenate([ids_c, np.zeros(pad, np.int64)])
             ids = shard[sel[:, None], cols[sel]]
             weight = np.concatenate([np.ones(n), np.zeros(pad)])
             echo = (t - tau[sel]).astype(np.float32)
-            part, loss = block(history, jnp.asarray(last[sel] + 1),
+            part, loss = block(history, frozen, jnp.asarray(last[sel] + 1),
                                jnp.asarray(weight, jnp.float32),
-                               jnp.asarray(echo), images, labels,
+                               jnp.asarray(echo), arrays,
                                jnp.asarray(ids, jnp.int32), eta)
             total = jax.tree.map(jnp.add, total, part)
             client_losses.append(np.asarray(loss)[:n])
@@ -282,8 +233,8 @@ def _norm_gap(prog_delta, ref_delta, offsets):
     """Worst leaf of |‖Δprog‖ - ‖Δref‖| / max(‖Δref‖, median leaf ‖Δref‖).
 
     Leaves the reference leaves unmoved to rounding (‖Δref‖ under a
-    thousandth of the median leaf's) are left out: under FedAWE every
-    CNN leaf moves, so none is left out at the cells' sizes."""
+    thousandth of the median leaf's) are left out, by that rule on the
+    reference and not by name."""
     p = [float(np.linalg.norm(x.astype(np.float64)))
          for x in split_leaves(prog_delta, offsets)]
     r = [float(np.linalg.norm(x.astype(np.float64)))

@@ -3,8 +3,8 @@
 The round function runs each layer under a ``jax.named_scope``.  The
 compiled program keeps the scope in each instruction's
 ``metadata={op_name="..."}``: a path such as
-``jit(chunk)/while/body/fl_local_sgd/transpose(jvp(...))/conv`` or, where
-the scope opens inside a transform, ``.../vmap(fl_local_sgd)/...``.
+``jit(chunk)/while/body/fl_local_sgd/transpose(jvp(...))/dot_general`` or,
+where the scope opens inside a transform, ``.../vmap(fl_local_sgd)/...``.
 ``instruction_scopes`` maps every instruction of the compiled program's
 text to the innermost ``fl_*`` component of its path, with the
 ``vmap(``/``jvp(``/``transpose(`` wrappers stripped; a fusion takes its
@@ -15,7 +15,7 @@ takes its fused root's scope; an unscoped instruction takes the scope of
 the loop or call that runs its computation (so everything inside local
 SGD's step loop is local SGD); one with no metadata at all, failing
 that, takes the scope its users share.  A trace operation is matched to
-its instruction by ``traces.instruction``, as the convolutions are.
+its instruction by ``traces.instruction``.
 
 The chunk executors mark every chunk with the host spans named below;
 ``host_spans`` gives the window's spans of one name.  A reading that

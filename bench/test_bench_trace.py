@@ -119,38 +119,6 @@ def test_compact_names_of_hlo_instructions():
     assert compact_name("not an instruction") == "not an instruction"
 
 
-HLO = """HloModule jit_chunk
-
-%fused_computation.115 (param_0: f32[4]) -> f32[4] {
-  %param_0 = f32[4]{0} parameter(0)
-  ROOT %convolution.3 = f32[4]{0} convolution(f32[4]{0} %param_0, f32[4]{0} %param_0), window={size=1}
-}
-
-%fused_computation.2 (param_0: f32[4]) -> f32[4] {
-  %param_0 = f32[4]{0} parameter(0)
-  ROOT %add.1 = f32[4]{0} add(f32[4]{0} %param_0, f32[4]{0} %param_0)
-}
-
-%body (p: (f32[4])) -> (f32[4]) {
-  %p = (f32[4]{0}) parameter(0)
-  %fusion.241 = f32[4]{0} fusion(f32[4]{0} %p), kind=kOutput, calls=%fused_computation.115
-  %fusion.7 = f32[4]{0} fusion(f32[4]{0} %fusion.241), kind=kLoop, calls=%fused_computation.2
-  ROOT %t = (f32[4]{0}) tuple(f32[4]{0} %fusion.7)
-}
-
-ENTRY %main.9 (a: f32[4]) -> f32[4] {
-  %a = f32[4]{0} parameter(0)
-  %while.96 = (f32[4]{0}) while((f32[4]{0}) %tuple), condition=%cond, body=%body
-  ROOT %convolution.9 = f32[4]{0} convolution(f32[4]{0} %a, f32[4]{0} %a)
-}
-"""
-
-
-def test_convolutions_found_in_fusions_not_loops():
-    assert opnames.instructions_with(HLO, "convolution") == {
-        "convolution.3", "fusion.241", "convolution.9"}
-
-
 def test_recorded_v5e_trace():
     """Two chunks (16 rounds) of ``cnn100_dense_sine`` traced on one v5e
     chip (names compacted, the Python tracer's host spans dropped)."""

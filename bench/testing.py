@@ -18,17 +18,18 @@ import json
 import sys
 import time
 
-from bench import harness
+from bench import harness, models
 
 SEED = 2**33 + 17     # a seed above 32 bits
 
 
 def tiny_cell(name: str) -> harness.Cell:
+    """The cell with its shapes cut to what a CPU test run can hold: the
+    model module's ``tiny`` sizes, at most 12 clients, a cohort of 4, s = 2,
+    batch 4, 20 samples a client and 2 rounds a chunk."""
     cell = harness.load_cell(name)
     cfg, tr = cell.cfg, cell.traffic
-    # the dense layer keeps thousands of weights so that a bf16 step, as
-    # at full size, loses most of its small updates
-    cfg["model"].update(input_shape=[8, 8, 3], channels=[4, 4], hidden=[4096])
+    models.of(cfg).tiny(cfg["model"])
     dep = cfg["deployment"]
     dep["m"] = min(dep["m"], 12)
     if dep["c_max"]:
@@ -52,16 +53,16 @@ def run_tiny(cell: harness.Cell, seed: int = SEED) -> dict:
 def _state_unchanged(mp):
     """Every round returns the state it was given."""
     from repro.core import engine
-    orig = engine.make_round_fn
+    orig = engine.make_round_fn_with_frozen
 
     def make(*a, **k):
         rf = orig(*a, **k)
 
-        def round_fn(state, batches):
-            _, metrics = rf(state, batches)
+        def round_fn(state, frozen, batches):
+            _, metrics = rf(state, frozen, batches)
             return state, metrics
         return round_fn
-    mp.setattr(engine, "make_round_fn", make)
+    mp.setattr(engine, "make_round_fn_with_frozen", make)
 
 
 def _half_batch(mp):
